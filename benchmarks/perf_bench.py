@@ -4,9 +4,9 @@ Measures requests-simulated/sec and the compile-vs-run split of the
 packed-state controller scan across policies x geometries x core counts,
 plus the scan ``unroll`` sweep that justifies the tuned default
 (``controller._SCAN_UNROLL``) and a **backend axis** (packed scan vs the
-fused Pallas kernels of ``repro.core.dram.pallas_step``; the compiled
-``pallas`` backend joins automatically when a TPU is attached, the
-``pallas-interpret`` CI leg always runs). A per-step microbenchmark
+fused Pallas kernels of ``repro.core.dram.pallas_step`` in interpret mode;
+the compiled ``pallas`` backend does not compile for a TPU and is refused
+by ``SimConfig``). A per-step microbenchmark
 (ns/step at two trace lengths per backend) makes kernel/block tuning
 reproducible instead of anecdotal. Everything runs on small CPU-friendly
 cells so the suite is CI-viable.
@@ -56,14 +56,8 @@ REF_REQ_PER_S = {
 }
 
 
-def _backends() -> tuple[str, ...]:
-    """Benchmarkable backends on this host: the packed scan and the Pallas
-    interpret leg always; the compiled kernel only where a TPU is attached
-    (Mosaic refuses to lower for CPU)."""
-    out = ["scan", "pallas-interpret"]
-    if any(d.platform == "tpu" for d in jax.devices()):
-        out.insert(1, "pallas")
-    return tuple(out)
+#: Benchmarked backends: the packed scan and the Pallas interpret leg.
+BACKENDS = ("scan", "pallas-interpret")
 
 
 def _prior_trajectory() -> list[dict]:
@@ -168,9 +162,7 @@ def run() -> dict:
                                   if c["name"] == "batch32/MASA/8x8"),
     }}
     tr = trace_for(workload("lbm"), N_PERF, cfg, SEED)
-    for backend in _backends():
-        if backend == "scan":
-            continue
+    for backend in BACKENDS[1:]:
         bcfg = SimConfig(backend=backend)
         c_single = _cell(
             f"single/MASA/8x8/{backend}", N_PERF,
@@ -191,7 +183,7 @@ def run() -> dict:
     # separate per-step cost from per-call cost — the number block-size /
     # unroll tuning actually needs.
     per_step = {}
-    for backend in _backends():
+    for backend in BACKENDS:
         bcfg = SimConfig(backend=backend)
         row = {}
         for n in (500, N_PERF):
@@ -255,7 +247,7 @@ def run() -> dict:
     host = {"platform": platform.system().lower() + "-" + platform.machine(),
             "cpu_count": os.cpu_count()}
     default_cell = next(c for c in cells if c["name"] == "single/MASA/8x8")
-    kernel_backend = "pallas" if "pallas" in backends else "pallas-interpret"
+    kernel_backend = "pallas-interpret"
     summary = {
         "default_req_per_s": default_cell["req_per_s"],
         "default_speedup_vs_ref": default_cell["speedup_vs_ref"],

@@ -33,7 +33,10 @@ so overlapping cells (every mechanism's baseline, notably) are simulated once.
 Output: ``name,us_per_call,derived`` CSV rows on stdout (one per paper
 table/figure entry) plus a single versioned JSON artifact (schema
 ``repro.bench/v1``, see docs/experiments.md) containing every summary, every
-sweep's full per-cell results, and cache statistics.
+sweep's full per-cell results, and cache statistics. A bench that raises
+prints ``<key>.FAILED`` (a missing module ``<key>.SKIPPED``), the others
+still run, and the process then exits non-zero. Compiled programs persist
+in JAX's compilation cache (``repro.compile_cache``).
 """
 from __future__ import annotations
 
@@ -155,7 +158,10 @@ def main(argv: list[str] | None = None) -> dict:
                  f"see --list")
 
     from benchmarks import common
+    from repro import compile_cache
     from repro.experiments import bench_artifact, write_artifact
+
+    compile_cache.enable()
 
     if args.journal:
         from repro.experiments import PersistentResultCache, install_global_cache
@@ -180,17 +186,20 @@ def main(argv: list[str] | None = None) -> dict:
     if args.only and args.suite:
         print(f"# note: --only={args.only} overrides --suite={args.suite}")
     summaries: dict[str, dict] = {}
+    failed: list[str] = []
     for b in select(args.suite, args.only):
         try:
             mod = importlib.import_module(b.module)
         except ModuleNotFoundError as e:
             print(f"{b.key}.SKIPPED,0.0,module_missing:{e.name}")
+            failed.append(b.key)
             continue
         t0 = time.perf_counter()
         try:
             summaries[b.key] = mod.run()
         except Exception as e:  # a failing bench must not hide the others
             print(f"{b.key}.FAILED,0.0,{type(e).__name__}:{e}")
+            failed.append(b.key)
             continue
         print(f"{b.key}.TOTAL,{(time.perf_counter()-t0)*1e6:.0f},ok")
 
@@ -220,6 +229,9 @@ def main(argv: list[str] | None = None) -> dict:
     print("\n# ---- summary vs paper ----")
     for key, summary in summaries.items():
         print(f"# {key}: {summary}")
+    if failed:
+        raise SystemExit(f"benchmarks that did not run to the end: "
+                         f"{', '.join(failed)}")
     return doc
 
 

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.core.dram import (PAPER_WORKLOADS, ROW_SPACE_STRIDE, Policy, Scheduler,
                              SimConfig, generate_trace, simulate, summarize,
@@ -89,6 +90,7 @@ def layer_c_train_and_serve():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     layer_a_dram()
     layer_b_kernel()
     layer_c_train_and_serve()

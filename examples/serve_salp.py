@@ -11,6 +11,7 @@ change results).
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.core.dram.policies import Policy
 from repro.models import build_model
@@ -33,6 +34,7 @@ def run_policy(policy: Policy, params, model, seed: int = 0):
 
 
 def main() -> None:
+    compile_cache.enable()
     cfg = get_config("phi3-mini-3.8b").reduced(64)
     model = build_model(cfg, dtype=jax.numpy.float32)
     params = model.init(jax.random.key(0))

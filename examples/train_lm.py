@@ -14,6 +14,7 @@ import tempfile
 
 import jax
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import DataPipeline
 from repro.models import build_model
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--fail-at-step", type=int, default=120)
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = get_config(args.arch).reduced(args.width)
     model = build_model(cfg, dtype=jax.numpy.float32)
     opt = make_optimizer(cfg.optimizer_mode, lr=1e-3, warmup=20,

@@ -61,6 +61,21 @@ BACKENDS = frozenset({"scan", "pallas", "pallas-interpret"})
 
 registry.register("backend", tuple(sorted(BACKENDS)))
 
+#: Why ``SimConfig`` refuses ``backend="pallas"``: the TPU compiler (Mosaic,
+#: JAX 0.9, for a v5e) refuses the fused kernels of
+#: :mod:`repro.core.dram.pallas_step` in two places, in this order.
+PALLAS_COMPILE_ERROR = (
+    "backend='pallas' does not compile for a TPU. Mosaic refuses the "
+    "kernels' (1, 1) blocks over (B, 1) arrays: 'The Pallas TPU lowering "
+    "currently requires that the last two dimensions of your block shape "
+    "are divisible by 8 and 128 respectively, or be equal to the respective "
+    "dimensions of the overall array'. With 3-D blocks it then refuses the "
+    "kernel body: 'Unimplemented primitive in Pallas TPU lowering for "
+    "KernelType.TC: scatter' (the completion-ring update "
+    "ring.at[i % _RING].set(comp) of controller._build_step1). Use "
+    "backend='scan' (the default) on the chip; backend='pallas-interpret' "
+    "is the CPU parity reference.")
+
 
 @dataclasses.dataclass(frozen=True)
 class SimConfig:
@@ -124,14 +139,15 @@ class SimConfig:
     #                        single-scan fast path when eligible (refresh
     #                        off, open rows); bit-identical either way.
     #   "pallas"           — the fused Pallas kernel
-    #                        (:mod:`repro.core.dram.pallas_step`): batch dim
-    #                        as the kernel grid axis, the packed state
-    #                        carried in-kernel across all steps. Compiles
-    #                        via Mosaic on TPU.
-    #   "pallas-interpret" — the same kernel with ``interpret=True`` so CPU
-    #                        CI executes the kernel's op graph without a
-    #                        TPU; the parity contract is enforced on this
-    #                        path.
+    #                        (:mod:`repro.core.dram.pallas_step`) compiled
+    #                        by Mosaic. REFUSED at construction: the TPU
+    #                        compiler rejects the kernel
+    #                        (``PALLAS_COMPILE_ERROR`` says where).
+    #   "pallas-interpret" — the same kernel with ``interpret=True``: batch
+    #                        dim as the kernel grid axis, the packed state
+    #                        carried across all steps, run through XLA on
+    #                        the CPU. The parity contract is enforced on
+    #                        this path.
     # A *static* axis: part of cache keys / bucket signatures like every
     # other field. The Pallas backends refuse ``emit_commands`` (the kernel
     # carries no per-step command log) — use backend="scan" for exports.
@@ -155,6 +171,8 @@ class SimConfig:
     def __post_init__(self) -> None:
         registry.resolve("backend", self.backend,
                          valid=tuple(sorted(BACKENDS)))
+        if self.backend == "pallas":
+            raise ValueError(PALLAS_COMPILE_ERROR)
         # Resolve the memtech spec first (typos raise the shared registry
         # error), then bind the technology's timing pack unless the caller
         # pinned an explicit DramTiming.

@@ -24,14 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 def ag_matmul_ring(x_shard: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     """Inside shard_map: x_shard [m/n, k] (sharded on rows), w [k, n] (local
     shard of a column-sharded W is fine too). Computes all_gather(x) @ w with
     the ring-overlap schedule. Returns [m, n]."""
-    n_dev = compat.axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
@@ -58,7 +56,7 @@ def collective_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
                       axis: str = "model") -> jax.Array:
     """y[m, n] = x[m, k] @ w[k, n], with x row-sharded over ``axis`` and the
     gather overlapped with compute. w is replicated over ``axis``."""
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(ag_matmul_ring, axis_name=axis),
         mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),

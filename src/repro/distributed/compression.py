@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 def _quant(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -35,7 +33,7 @@ def _dequant(q: jax.Array, scale: jax.Array) -> jax.Array:
 def int8_ring_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     """Inside shard_map: mean-all-reduce of x over ``axis_name`` with int8
     payloads on every hop (reduce-scatter ring + all-gather ring)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -84,7 +82,7 @@ def compressed_mean(x: jax.Array, mesh: Mesh, axis: str = "pod") -> jax.Array:
     """x [n_axis, ...]: row i is device-group i's local value (e.g. pod-local
     gradients). Returns the same shape with every row replaced by the mean,
     computed with int8 ring hops over ``axis``."""
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(int8_ring_allreduce, axis_name=axis),
         mesh=mesh,
         in_specs=P(axis),

@@ -210,12 +210,16 @@ class ResiliencePolicy:
     The defaults favour forward progress: two retries with short exponential
     backoff, then bisection down to single cells. ``bisect=False`` degrades
     to all-or-nothing per bucket (the pre-resilience behaviour, minus the
-    abort). ``sleep`` is injectable so tests never actually wait.
+    abort). ``fail_fast=True`` re-raises the first failure as it is, with
+    no retry, bisection or quarantine — for a smoke run that must see the
+    error itself (``chip_smoke.py``). ``sleep`` is injectable so tests never
+    actually wait.
     """
     max_retries: int = 2
     backoff_base_s: float = 0.05
     backoff_factor: float = 2.0
     bisect: bool = True
+    fail_fast: bool = False
     straggler_threshold: float = 2.5
     sleep: Callable[[float], None] = time.sleep
 
@@ -314,6 +318,8 @@ def execute_buckets(
                     out = fault_plan.after(bucket, idxs, out)
                 return out, None, n
             except Exception as e:  # noqa: BLE001 — isolation boundary
+                if policy.fail_fast:
+                    raise
                 last = e
                 if try_no < policy.max_retries:
                     report.retries += 1

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 _NEG_INF = -1e30
 
@@ -81,7 +80,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),   # l
             pltpu.VMEM((bq, hd), jnp.float32),    # acc
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

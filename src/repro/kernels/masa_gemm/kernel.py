@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _kernel_os(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     k = pl.program_id(2)
@@ -71,7 +69,7 @@ def masa_gemm_kernel(a: jax.Array, b: jax.Array, *,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(a, b)
@@ -85,7 +83,7 @@ def masa_gemm_kernel(a: jax.Array, b: jax.Array, *,
                       pl.BlockSpec((k, bn), lambda j, i: (0, j))],
             out_specs=pl.BlockSpec((bm, bn), lambda j, i: (i, j)),
             out_shape=out_shape,
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(a, b)

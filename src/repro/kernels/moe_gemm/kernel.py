@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _body(eids_ref, x_ref, w_ref, o_ref):
     o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
@@ -48,7 +46,7 @@ def grouped_matmul_kernel(x_sorted: jax.Array, w: jax.Array,
         _body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, f), x_sorted.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_eids, x_sorted, w)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 _NEG_INF = -1e30
 
@@ -92,7 +91,7 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         functools.partial(_body, page=page, n_pages=n_pages, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, kvh, g, hd), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_table, seq_lens, q, k_pages, v_pages)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _ssd_body(xr_ref, l_ref, b_ref, c_ref, y_ref, hT_ref, state_ref, *,
               n_chunks: int):
@@ -91,7 +89,7 @@ def ssd_scan_kernel(xr: jax.Array, l: jax.Array, b: jax.Array, c: jax.Array, *,
             jax.ShapeDtypeStruct((bh, ds, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((ds, hd), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xr, l, b, c)

@@ -9,16 +9,19 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Whatever devices exist locally, as a 1D 'data' mesh (tests, examples)."""
     n = jax.device_count()
-    return compat.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))

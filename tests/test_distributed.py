@@ -9,10 +9,9 @@ import textwrap
 
 import pytest
 
-# Seed distributed stack, revived against the installed JAX via
-# ``repro.compat`` (the `jax.sharding.AxisType` / `jax.shard_map` drift is
-# absorbed there) — the toolchain-revival leg of ROADMAP open item 1. The
-# ``seed_kernel`` marker stays for suite selection.
+# Seed distributed stack, written against the installed JAX API
+# (``jax.make_mesh(axis_types=)``, ``jax.shard_map``, ``jax.lax.axis_size``).
+# The ``seed_kernel`` marker stays for suite selection.
 pytestmark = pytest.mark.seed_kernel
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -33,7 +32,7 @@ def test_sharded_train_step_matches_single_device():
     updated params as the single-device run (GSPMD correctness)."""
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
+        from jax.sharding import AxisType
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.data.synth import make_batch
@@ -53,7 +52,8 @@ def test_sharded_train_step_matches_single_device():
         # single device reference
         p1, s1, m1 = jax.jit(step)(params, state, batch, jnp.int32(0))
 
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         pspecs = param_pspecs(cfg, params, mesh)
         shard = lambda t, s: jax.device_put(t, NamedSharding(mesh, s))
         params_sh = jax.tree.map(shard, params, pspecs)
@@ -76,9 +76,10 @@ def test_sharded_train_step_matches_single_device():
 def test_collective_matmul_matches_reference():
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
+        from jax.sharding import AxisType
         from repro.distributed.collective_matmul import collective_matmul
-        mesh = compat.make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",),
+                             axis_types=(AxisType.Auto,))
         x = jax.random.normal(jax.random.key(0), (64, 32), jnp.float32)
         w = jax.random.normal(jax.random.key(1), (32, 48), jnp.float32)
         y = collective_matmul(x, w, mesh, axis="model")
@@ -91,9 +92,10 @@ def test_collective_matmul_matches_reference():
 def test_int8_ring_allreduce_and_error_feedback():
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
+        from jax.sharding import AxisType
         from repro.distributed.compression import compressed_mean, ef_compress_update
-        mesh = compat.make_mesh((8,), ("pod",))
+        mesh = jax.make_mesh((8,), ("pod",),
+                             axis_types=(AxisType.Auto,))
         x = jax.random.normal(jax.random.key(0), (8, 1024), jnp.float32)
         out = compressed_mean(x, mesh, axis="pod")
         want = jnp.broadcast_to(x.mean(0), (8, 1024))
@@ -120,7 +122,7 @@ def test_decode_sharded_equals_single():
     """Flash-decoding style seq-sharded KV decode == single-device decode."""
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
+        from jax.sharding import AxisType
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.data.synth import make_batch
@@ -138,7 +140,8 @@ def test_decode_sharded_equals_single():
         tok = jnp.full((4, 1), 7, jnp.int32)
         ref, _ = jax.jit(model.decode_step)(params, tok, cache, jnp.int32(16))
 
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         pspecs = param_pspecs(cfg, params, mesh)
         cspecs = cache_pspecs(cfg, mesh, batch=4)
         shard = lambda t, s: jax.device_put(t, NamedSharding(mesh, s))
@@ -156,12 +159,13 @@ def test_decode_sharded_equals_single():
 def test_param_pspecs_cover_all_archs():
     """Every arch's param tree gets a valid spec (single process, no devices)."""
     import jax
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.configs import get_config, list_archs
     from repro.distributed.sharding import param_pspecs
     from repro.models import build_model
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     for arch in list_archs():
         cfg = get_config(arch)
         model = build_model(cfg.reduced())
@@ -179,7 +183,7 @@ def test_elastic_restore_across_meshes():
     run_subprocess("""
         import tempfile
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
+        from jax.sharding import AxisType
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.store import load_checkpoint, save_checkpoint
         from repro.configs import get_config
@@ -190,7 +194,8 @@ def test_elastic_restore_across_meshes():
         model = build_model(cfg, dtype=jnp.float32)
         params = model.init(jax.random.key(0))
 
-        mesh_a = compat.make_mesh((2, 4), ("data", "model"))
+        mesh_a = jax.make_mesh((2, 4), ("data", "model"),
+                               axis_types=(AxisType.Auto,) * 2)
         specs = param_pspecs(cfg, params, mesh_a)
         sharded = jax.tree.map(
             lambda t, s: jax.device_put(t, NamedSharding(mesh_a, s)),
@@ -201,7 +206,8 @@ def test_elastic_restore_across_meshes():
             step, restored, _ = load_checkpoint(d, template=params)
             assert step == 3
             # re-shard onto a DIFFERENT mesh
-            mesh_b = compat.make_mesh((4, 2), ("data", "model"))
+            mesh_b = jax.make_mesh((4, 2), ("data", "model"),
+                                   axis_types=(AxisType.Auto,) * 2)
             specs_b = param_pspecs(cfg, params, mesh_b)
             resharded = jax.tree.map(
                 lambda t, s: jax.device_put(t, NamedSharding(mesh_b, s)),

@@ -12,10 +12,8 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 TOLS = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
         jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
 
-# The whole module exercises a seed Pallas kernel, revived against the
-# installed JAX via ``repro.compat`` (the pltpu.CompilerParams rename is
-# absorbed there) — ROADMAP open item 1's toolchain-revival leg. The
-# ``seed_kernel`` marker stays for suite selection.
+# The whole module exercises a seed Pallas kernel (interpret mode on CPU).
+# The ``seed_kernel`` marker stays for suite selection.
 pytestmark = pytest.mark.seed_kernel
 
 

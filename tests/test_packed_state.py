@@ -31,8 +31,8 @@ GOLDEN_PATH = os.path.join(os.path.dirname(__file__), "data",
 #: Execution backends under test. "pallas-interpret" runs the fused Pallas
 #: kernels (repro.core.dram.pallas_step) with interpret=True — the CPU/CI
 #: leg of the bit-parity contract; "scan" is the packed lax.scan reference.
-#: The compiled "pallas" backend needs a TPU and is exercised by the same
-#: parametrization wherever one is attached.
+#: The compiled "pallas" backend is refused at SimConfig construction (the
+#: TPU compiler rejects the kernel; see engine.PALLAS_COMPILE_ERROR).
 BACKENDS = ("scan", "pallas-interpret")
 
 #: Refresh-engaged timing for the ladder's fixture cells (see CONFIGS).
@@ -204,18 +204,26 @@ def test_stacked_equals_per_trace_simulate(combo, backend):
 
 
 def test_pallas_refuses_emit_commands():
-    """emit_commands x pallas must raise, never silently drop the log."""
+    """emit_commands x pallas must raise, never silently drop the log; the
+    compiled backend is refused before that, at construction, with the TPU
+    compiler's reason."""
     from repro.core.dram.commands import simulate_commands
     from repro.core.dram.trace import stack_traces as _stack
 
+    for kw in ({}, {"emit_commands": True}):
+        with pytest.raises(ValueError, match="Unimplemented primitive in "
+                                             "Pallas TPU lowering"):
+            SimConfig(backend="pallas", **kw)
+    with pytest.raises(ValueError, match="block shape"):
+        dataclasses.replace(SimConfig(), backend="pallas")
+
     tr = random_trace(5, n=16)
-    for backend in ("pallas", "pallas-interpret"):
-        cfg = SimConfig(backend=backend)
-        with pytest.raises(ValueError, match="emit_commands"):
-            simulate_commands(tr, Policy.MASA, cfg)
-        with pytest.raises(ValueError, match="emit_commands"):
-            simulate_stacked(_stack([tr]), Policy.MASA,
-                             dataclasses.replace(cfg, emit_commands=True))
+    cfg = SimConfig(backend="pallas-interpret")
+    with pytest.raises(ValueError, match="emit_commands"):
+        simulate_commands(tr, Policy.MASA, cfg)
+    with pytest.raises(ValueError, match="emit_commands"):
+        simulate_stacked(_stack([tr]), Policy.MASA,
+                         dataclasses.replace(cfg, emit_commands=True))
 
 
 def test_scan_commands_match_pallas_counters():

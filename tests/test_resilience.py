@@ -145,6 +145,16 @@ class TestExecuteBuckets:
         assert sorted(q.index for q in report.quarantined) == [0, 1, 2, 3]
         assert report.bisections == 0
 
+    def test_fail_fast_reraises_first_error(self):
+        got = {}
+        plan = FaultPlan.parse("oom@b1:x1")
+        with pytest.raises(SimulatedOOM, match="injected OOM at bucket 1"):
+            execute_buckets([[0], [1], [2]], fake_sim, got.update,
+                            policy=dataclasses.replace(FAST, fail_fast=True),
+                            fault_plan=plan)
+        assert got == fake_sim([0])   # no retry, no bisection, no bucket 2
+        assert len(plan.log) == 1
+
     def test_kill_propagates_and_keeps_committed_buckets(self):
         got = {}
         with pytest.raises(SweepKilled):

@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device,
+percent: 100 * (1 - busy / window), from the profiler's trace
+(``xplane.reduce``). Until the runner has spans of its own, this stands for
+all the host work of a sweep: trace generation, stacking, readback."""
+
+
+def read(run):
+    if run.trace is None or run.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])

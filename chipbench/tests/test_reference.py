@@ -1,0 +1,74 @@
+"""The plain reference agrees with the program bit for bit at small sizes,
+on every policy, with refresh off and under DARP, and on mixes under
+FR-FCFS and TCM. (At the cells' sizes the benchmark compares them itself.)
+"""
+import dataclasses
+import json
+
+import numpy as np
+import pytest
+
+import reference
+from conftest import BENCH, SEEDS
+
+CFG1 = json.loads((BENCH / "configs" / "ddr3_1066_1core.json").read_text())
+CFG4 = json.loads((BENCH / "configs" / "ddr3_1066_4core.json").read_text())
+FIG4 = json.loads((BENCH / "traffic" / "fig4.json").read_text())
+MIXES = json.loads((BENCH / "traffic" / "mixes.json").read_text())
+N = 300
+
+
+def program_config(cfg, **over):
+    from repro.core.dram import SimConfig
+    from repro.core.dram.timing import DramTiming
+    return SimConfig(n_banks=cfg["n_banks"], n_subarrays=cfg["n_subarrays"],
+                     timing=DramTiming(**cfg["timing"]), **over)
+
+
+def as_ints(res):
+    return {k: int(np.asarray(v)) for k, v in dataclasses.asdict(res).items()}
+
+
+@pytest.mark.parametrize("refresh", ["none", "darp"])
+@pytest.mark.parametrize("index", [0, 13, 22, 27, 31])
+def test_single_core(index, refresh):
+    from repro.core.dram import Policy, WorkloadProfile, generate_trace
+    from repro.core.dram import simulate
+    prof = FIG4["workloads"][index]
+    seed = SEEDS[index % len(SEEDS)]
+    tr = generate_trace(WorkloadProfile(**prof), N, seed=seed)
+    cfg = {**CFG1, "refresh_policy": refresh}
+    rt = reference.generate_trace(prof, N, seed, cfg)
+    for k in ("bank", "subarray", "row", "is_write", "gap", "dep"):
+        assert getattr(tr, k).tolist() == rt[k], k
+    assert tr.mlp_window == rt["mlp_window"]
+    for pol in Policy:
+        got = as_ints(simulate(tr, pol, program_config(
+            CFG1, refresh_policy=refresh)))
+        assert got == reference.simulate(rt, pol.name, cfg), pol.name
+
+
+@pytest.mark.parametrize("scheduler", ["FRFCFS", "TCM"])
+@pytest.mark.parametrize("mix", [0, 1, 2, 3])
+def test_mix(mix, scheduler):
+    from repro.core.dram import (Policy, Scheduler, WorkloadProfile,
+                                 generate_trace)
+    from repro.core.dram.multicore import simulate_multicore
+    profs = MIXES["mixes"][mix]
+    seed, stride = SEEDS[mix % len(SEEDS)], CFG4["row_space_stride"]
+    trs = [generate_trace(WorkloadProfile(**p), N, seed=seed,
+                          row_space_offset=stride * i)
+           for i, p in enumerate(profs)]
+    cfg = {**CFG4, "refresh_policy": "none"}
+    rts = [reference.generate_trace(p, N, seed, cfg,
+                                    row_space_offset=stride * i)
+           for i, p in enumerate(profs)]
+    for pol in Policy:
+        r = simulate_multicore(trs, pol, program_config(
+            CFG4, scheduler=Scheduler[scheduler]))
+        want = reference.simulate_mix(rts, [p["mpki"] for p in profs],
+                                      pol.name, scheduler, cfg)
+        assert as_ints(r.shared) == want["counters"], pol.name
+        assert [int(x) for x in r.core_cycles] == want["core_cycles"]
+        assert [float(x) for x in r.alone_cycles] == want["alone_cycles"]
+        assert r.weighted_speedup == want["weighted_speedup"]

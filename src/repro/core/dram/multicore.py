@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.dram import controller
 from repro.core.dram.engine import SimConfig, SimResult, _controller_args
 from repro.core.dram.policies import Policy
@@ -101,45 +102,49 @@ def simulate_multicore_batch(mixes: list[list[Trace]], policy: Policy,
     """
     config = _scheduler_for(config, use_ranking)
     eff, sched, nb, ns = _controller_args(policy, config)
-    prepped = [_prep_mix(m, policy, config) for m in mixes]
-    stacked = {k: jnp.asarray(np.stack([st[k] for st, _ in prepped]))
-               for k in prepped[0][0]}
-    ranks = jnp.asarray(np.stack([r for _, r in prepped]))
-    controller.validate_mlp_window(stacked["mlp_window"])
+    with spans.span("repro.bucket.stage"):
+        prepped = [_prep_mix(m, policy, config) for m in mixes]
+        stacked = {k: jnp.asarray(np.stack([st[k] for st, _ in prepped]))
+                   for k in prepped[0][0]}
+        ranks = jnp.asarray(np.stack([r for _, r in prepped]))
+        controller.validate_mlp_window(stacked["mlp_window"])
 
-    if config.backend != "scan":
-        # fused Pallas mix kernel: the mix dimension is the kernel grid
-        # axis, no outer vmap (docs/kernels.md). Refuses emit_commands.
-        from repro.core.dram import pallas_step
-        pallas_step.check_no_emit(config)
-        shared, core_cycles = pallas_step._simulate_cores_pallas(
-            eff, sched, nb, ns, config.timing, config.refresh_mode,
-            stacked["bank"], stacked["subarray"], stacked["row"],
-            stacked["is_write"], stacked["gap"], stacked["dep"],
-            stacked["mlp_window"], ranks,
-            closed_row=config.row_policy == "closed",
-            interpret=config.backend == "pallas-interpret")
-    else:
-        fn = _controller_fn(eff, sched, nb, ns, config)
-        shared, core_cycles = jax.vmap(fn)(
-            stacked["bank"], stacked["subarray"], stacked["row"],
-            stacked["is_write"], stacked["gap"], stacked["dep"],
-            stacked["mlp_window"], ranks)
+        if config.backend != "scan":
+            # fused Pallas mix kernel: the mix dimension is the kernel grid
+            # axis, no outer vmap (docs/kernels.md). Refuses emit_commands.
+            from repro.core.dram import pallas_step
+            pallas_step.check_no_emit(config)
+            shared, core_cycles = pallas_step._simulate_cores_pallas(
+                eff, sched, nb, ns, config.timing, config.refresh_mode,
+                stacked["bank"], stacked["subarray"], stacked["row"],
+                stacked["is_write"], stacked["gap"], stacked["dep"],
+                stacked["mlp_window"], ranks,
+                closed_row=config.row_policy == "closed",
+                interpret=config.backend == "pallas-interpret")
+        else:
+            fn = _controller_fn(eff, sched, nb, ns, config)
+            shared, core_cycles = jax.vmap(fn)(
+                stacked["bank"], stacked["subarray"], stacked["row"],
+                stacked["is_write"], stacked["gap"], stacked["dep"],
+                stacked["mlp_window"], ranks)
 
     alone_all = (alone_cycles if alone_cycles is not None
                  else alone_baseline_cycles(mixes, config))
 
-    out = []
-    pos = 0
-    for i, m in enumerate(mixes):
-        res_i = SimResult(**{f.name: np.asarray(getattr(shared, f.name))[i]
-                             for f in dataclasses.fields(SimResult)})
-        out.append(MulticoreResult(
-            shared=res_i,
-            core_cycles=np.asarray(core_cycles, np.float64)[i],
-            alone_cycles=alone_all[pos:pos + len(m)],
-            profiles=[t.profile for t in m]))
-        pos += len(m)
+    with spans.span("repro.bucket.device_wait"):
+        jax.block_until_ready((shared, core_cycles))
+    with spans.span("repro.bucket.readback"):
+        out = []
+        pos = 0
+        for i, m in enumerate(mixes):
+            res_i = SimResult(**{f.name: np.asarray(getattr(shared, f.name))[i]
+                                 for f in dataclasses.fields(SimResult)})
+            out.append(MulticoreResult(
+                shared=res_i,
+                core_cycles=np.asarray(core_cycles, np.float64)[i],
+                alone_cycles=alone_all[pos:pos + len(m)],
+                profiles=[t.profile for t in m]))
+            pos += len(m)
     return out
 
 

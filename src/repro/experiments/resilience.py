@@ -16,8 +16,9 @@ the simulator":
 * **Quarantine** — cells that still fail alone are recorded (error, attempts,
   originating bucket) in a structured ``quarantined`` list that the runner
   surfaces in the ``repro.sweep/v1`` artifact; the sweep completes.
-* **Watchdog** — per-bucket wall time feeds a
-  :class:`repro.fault.StepWatchdog` EWMA; stragglers land in artifact stats.
+* **Watchdog** — each attempt's ``repro.bucket`` span
+  (:mod:`repro.spans`) feeds a :class:`repro.fault.StepWatchdog` EWMA;
+  stragglers land in artifact stats.
 * **Deterministic fault injection** — :class:`FaultPlan` raises / OOMs /
   delays / corrupts counters at named bucket or cell indices, so every path
   above is exercised by tests and CI instead of merely trusted
@@ -33,6 +34,7 @@ import dataclasses
 import time
 from typing import Any, Callable, Iterable, Sequence
 
+from repro import spans
 from repro.fault.watchdog import StepWatchdog
 
 
@@ -302,16 +304,15 @@ def execute_buckets(
         n = 0
         for try_no in range(policy.max_retries + 1):
             n += 1
-            t0 = time.perf_counter()
             try:
-                if fault_plan is not None:
-                    fault_plan.before(bucket, idxs)
-                out = simulate_fn(list(idxs))
-                elapsed = time.perf_counter() - t0
-                if watchdog.observe_step(report.n_batches, elapsed):
+                with spans.span("repro.bucket") as step:
+                    if fault_plan is not None:
+                        fault_plan.before(bucket, idxs)
+                    out = simulate_fn(list(idxs))
+                if watchdog.observe_step(report.n_batches, step.elapsed_s):
                     report.stragglers.append(
                         {"bucket": bucket, "n_cells": len(idxs),
-                         "elapsed_s": round(elapsed, 6),
+                         "elapsed_s": round(step.elapsed_s, 6),
                          "ewma_s": round(watchdog.events[-1].ewma, 6)})
                 report.n_batches += 1
                 if fault_plan is not None:
@@ -323,8 +324,9 @@ def execute_buckets(
                 last = e
                 if try_no < policy.max_retries:
                     report.retries += 1
-                    policy.sleep(policy.backoff_base_s
-                                 * policy.backoff_factor ** try_no)
+                    with spans.span("repro.resilience.backoff"):
+                        policy.sleep(policy.backoff_base_s
+                                     * policy.backoff_factor ** try_no)
         return None, last, n
 
     def run_isolated(bucket: int, idxs: list[int]) -> None:

@@ -34,11 +34,13 @@ journal — immediately, so a crash never loses finished work.
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Any, Iterable
+import functools
+from typing import Any, Callable, Iterable
 
+import jax
 import numpy as np
 
+from repro import spans
 from repro.core.dram import engine
 from repro.core.dram.engine import SimConfig, SimResult
 from repro.core.dram.metrics import (avg_read_latency, energy_from_result,
@@ -83,11 +85,12 @@ def trace_for(workload: WorkloadProfile, n_requests: int, config: SimConfig,
            row_space_offset, config.mapping, footprint_rows)
     tr = _TRACE_CACHE.get(key)
     if tr is None:
-        tr = generate_trace(workload, n_requests, n_banks=config.n_banks,
-                            n_subarrays=config.n_subarrays, seed=seed,
-                            row_space_offset=row_space_offset,
-                            mapping=config.mapping,
-                            footprint_rows=footprint_rows)
+        with spans.span("repro.trace.generate"):
+            tr = generate_trace(workload, n_requests, n_banks=config.n_banks,
+                                n_subarrays=config.n_subarrays, seed=seed,
+                                row_space_offset=row_space_offset,
+                                mapping=config.mapping,
+                                footprint_rows=footprint_rows)
         _TRACE_CACHE[key] = tr
     return tr
 
@@ -238,6 +241,24 @@ def _resolve_plan(shards: "ShardPlan | int | None",
     return ShardPlan(1) if fragment_dir is not None else None
 
 
+def _recorded(sweep_fn: Callable) -> Callable:
+    """Run a sweep entry inside a span recording of its own, the whole call
+    spanned as ``repro.sweep``: its ``stats`` gain ``elapsed_s`` (that
+    span's total) and ``spans`` (every span the sweep closed, see
+    :mod:`repro.spans`)."""
+    @functools.wraps(sweep_fn)
+    def run(*args, **kwargs):
+        with spans.recording() as rec:
+            with spans.span("repro.sweep"):
+                sweep = sweep_fn(*args, **kwargs)
+        summary = rec.summary()
+        sweep.stats["elapsed_s"] = round(summary["repro.sweep"]["total_s"], 4)
+        sweep.stats["spans"] = summary
+        return sweep
+    return run
+
+
+@_recorded
 def run_sweep(grid: SweepGrid, cache: ResultCache | None = None, *,
               resilience: ResiliencePolicy | None = None,
               fault_plan: FaultPlan | None = None,
@@ -263,43 +284,52 @@ def run_sweep(grid: SweepGrid, cache: ResultCache | None = None, *,
     cache = cache if cache is not None else ResultCache()
     resilience = resilience or ResiliencePolicy()
     plan = _resolve_plan(shards, fragment_dir)
-    t0 = time.perf_counter()
     cells = grid.expand()
 
     traces = [trace_for(c.workload, grid.n_requests, c.config, grid.seed,
                         footprint_rows=grid.footprint_rows)
               for c in cells]
-    keys = [cell_key(tr, c.policy, c.config) for tr, c in zip(traces, cells)]
+    with spans.span("repro.cache.key"):
+        keys = [cell_key(tr, c.policy, c.config)
+                for tr, c in zip(traces, cells)]
 
     # Partition: cached / to-simulate (deduping repeated keys within the sweep).
     counters_by_key: dict[str, dict[str, int]] = {}
     hit_keys: set[str] = set()
     pending: dict[tuple, list[int]] = {}   # bucket -> cell indices (first per key)
     seen_pending: set[str] = set()
-    for i, (c, k) in enumerate(zip(cells, keys)):
-        if k in counters_by_key or k in seen_pending:
-            continue
-        got = cache.get(k)
-        if got is not None:
-            counters_by_key[k] = got
-            hit_keys.add(k)
-        else:
-            pending.setdefault(_bucket_key(c, grid.n_requests), []).append(i)
-            seen_pending.add(k)
+    with spans.span("repro.cache.lookup"):
+        for i, (c, k) in enumerate(zip(cells, keys)):
+            if k in counters_by_key or k in seen_pending:
+                continue
+            got = cache.get(k)
+            if got is not None:
+                counters_by_key[k] = got
+                hit_keys.add(k)
+            else:
+                pending.setdefault(_bucket_key(c, grid.n_requests), []).append(i)
+                seen_pending.add(k)
 
     # One batched simulator call per static-shape (sub-)bucket, fault-isolated.
     def simulate_bucket(idxs: list[int]) -> dict[int, dict[str, int]]:
-        stacked = stack_traces([traces[i] for i in idxs])
-        res = _SIMULATE(stacked, cells[idxs[0]].policy, cells[idxs[0]].config)
-        unpacked = {f: np.asarray(getattr(res, f)) for f in _COUNTER_FIELDS}
-        return {i: {f: int(unpacked[f][b]) for f in _COUNTER_FIELDS}
-                for b, i in enumerate(idxs)}
+        with spans.span("repro.bucket.stage"):
+            stacked = stack_traces([traces[i] for i in idxs])
+            res = _SIMULATE(stacked, cells[idxs[0]].policy,
+                            cells[idxs[0]].config)
+        with spans.span("repro.bucket.device_wait"):
+            jax.block_until_ready(res)
+        with spans.span("repro.bucket.readback"):
+            unpacked = {f: np.asarray(getattr(res, f))
+                        for f in _COUNTER_FIELDS}
+            return {i: {f: int(unpacked[f][b]) for f in _COUNTER_FIELDS}
+                    for b, i in enumerate(idxs)}
 
     def commit_bucket(out: dict[int, dict[str, int]]) -> None:
-        for i, counters in out.items():
-            counters_by_key[keys[i]] = counters
-            cache.put(keys[i], counters)
-        cache.flush()   # crash consistency: journal the bucket before moving on
+        with spans.span("repro.cache.commit"):
+            for i, counters in out.items():
+                counters_by_key[keys[i]] = counters
+                cache.put(keys[i], counters)
+            cache.flush()   # crash consistency: journal the bucket before moving on
 
     def q_record(q) -> dict[str, Any]:
         return {"index": q.index, "workload": cells[q.index].workload.name,
@@ -364,7 +394,6 @@ def run_sweep(grid: SweepGrid, cache: ResultCache | None = None, *,
                             - len(report.quarantined)),
         "sim_batches": report.n_batches,
         "quarantined_cells": len(cells) - len(results),
-        "elapsed_s": round(time.perf_counter() - t0, 4),
         **report.stats(),
     }
     if plan is not None:
@@ -478,6 +507,7 @@ class MixSweepResult:
         }
 
 
+@_recorded
 def run_mix_sweep(grid: MixGrid, *,
                   resilience: ResiliencePolicy | None = None,
                   fault_plan: FaultPlan | None = None,
@@ -502,7 +532,6 @@ def run_mix_sweep(grid: MixGrid, *,
 
     resilience = resilience or ResiliencePolicy()
     plan = _resolve_plan(shards, fragment_dir)
-    t0 = time.perf_counter()
     cells = grid.expand()
 
     def mix_traces(cell: MixCell) -> list[Trace]:
@@ -519,7 +548,8 @@ def run_mix_sweep(grid: MixGrid, *,
         ref_cfg = dataclasses.replace(cell.config, scheduler=Scheduler.FCFS)
         key = (dataclasses.astuple(ref_cfg), cell.mix_index)
         if key not in alone_memo:
-            alone_memo[key] = alone_baseline_cycles([traces], ref_cfg)
+            with spans.span("repro.mix.alone_baseline"):
+                alone_memo[key] = alone_baseline_cycles([traces], ref_cfg)
         return alone_memo[key]
 
     buckets: dict[tuple, list[int]] = {}
@@ -582,7 +612,6 @@ def run_mix_sweep(grid: MixGrid, *,
         "n_cores": grid.n_cores,
         "sim_batches": report.n_batches,
         "quarantined_cells": len(cells) - len(results),
-        "elapsed_s": round(time.perf_counter() - t0, 4),
         **report.stats(),
     }
     if plan is not None:

@@ -48,14 +48,6 @@ class StepWatchdog:
                      else (1 - self.alpha) * self.ewma + self.alpha * step_time)
         return straggler
 
-    def summary(self) -> dict:
-        """Artifact-friendly digest (embedded in sweep stats by the runner)."""
-        return {
-            "ewma_s": None if self.ewma is None else round(self.ewma, 6),
-            "n_stragglers": len(self.events),
-            "threshold": self.threshold,
-        }
-
     def observe_heartbeat(self, count: int) -> bool:
         """Feed the data-pipeline heartbeat counter; True if wedged."""
         now = time.monotonic()

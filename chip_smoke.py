@@ -11,8 +11,8 @@ suites' full size (``N_REQUESTS`` requests per trace):
   (32 workloads x 5 policies, DDR3-1066, 8 banks x 8 subarrays) through
   ``run_sweep``: the lane-batched scan.
 * **Phase B, refresh on** — the same 32 workloads under BASELINE and MASA
-  with DARP refresh at the 8 Gb preset: the vmapped per-trace controller
-  scan.
+  with DARP refresh at the 8 Gb preset: the lane-batched scan's refresh
+  step.
 * **Phase C, mixes** — the four 4-core mixes of ``benchmarks.multicore_bench``
   x 5 policies x {FR-FCFS, TCM} through ``run_mix_sweep``: the C-core step.
 * **Golden replay** — every cell of ``tests/data/golden_packed_state.json``

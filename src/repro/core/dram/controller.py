@@ -119,19 +119,25 @@ def _refresh_fns(policy: int, t: DramTiming, n_subarrays: int,
     """Build the three refresh closures shared by every executor.
 
     Returned as ``(head_visibility, update_ref, ref_cmds)``; the scan paths
-    in :func:`_simulate_controller` and the Pallas kernel bodies
-    (:mod:`repro.core.dram.pallas_step`) call the SAME functions, so the
-    refresh semantics cannot diverge between backends.
+    in :func:`_simulate_controller` and :func:`_simulate_stacked_lanes` and
+    the Pallas kernel bodies (:mod:`repro.core.dram.pallas_step`) call the
+    SAME functions, so the refresh semantics cannot diverge between
+    executors. The closures are elementwise math on refresh rows the caller
+    has already gathered; reading the table and writing it back is the
+    caller's contract, as the block movement around ``engine._step_math``
+    is.
     """
     is_masa = policy == Policy.MASA
     zero = jnp.int32(0)
 
-    def head_visibility(ref, vis, hb, hs, hwr):
+    def head_visibility(refb, vis, hs, hwr):
         """Refresh gating of one step's head visibility (shared C=1 / C>1).
 
-        ``vis/hb/hs/hwr`` are [C] vectors (or scalars for the C=1 fast
-        path); returns the gated ``vis`` plus the refresh directive for the
-        heads. ``refresh_mode`` dispatch is static (Python branches):
+        ``refb`` is the head bank's refresh row, fields first (``[REF_F]``,
+        or ``[REF_F, C]`` / ``[REF_F, B]`` for vectors of heads or lanes);
+        ``vis/hs/hwr`` are matching [C] / [B] vectors (or scalars for the
+        C=1 fast path). Returns the gated ``vis`` plus the refresh directive
+        for the heads. ``refresh_mode`` dispatch is static (Python branches):
 
         * modes 1/2 (REFab / DSARP) — the historical deadline machinery,
           kept literally unchanged (regression-pinned bit-for-bit);
@@ -145,11 +151,6 @@ def _refresh_fns(policy: int, t: DramTiming, n_subarrays: int,
           ``update_ref``, where the write's completion cycle is known); only
           debt overflowing the window forces a blocking burst.
         """
-        if not refresh_mode:
-            return vis, None
-        refb = jnp.moveaxis(
-            jax.lax.dynamic_slice(ref, (hb, zero), (1, L.REF_F))[0], -1, 0) \
-            if jnp.ndim(hb) == 0 else jnp.moveaxis(ref[hb], -1, 0)
         busy_end = refb[L.REF_BUSY_UNTIL]
         if refresh_mode in (1, 2):
             # a burst already started by an earlier step still blocks the bank
@@ -246,28 +247,34 @@ def _refresh_fns(policy: int, t: DramTiming, n_subarrays: int,
                      n_forced=n_forced, forced_at=forced_at)
         return vis, d
 
-    def update_ref(ref, directive, hb, vis, comp):
-        """Commit the served bank's refresh row (scalar ``hb``/``vis``)."""
-        old_row = jax.lax.dynamic_slice(ref, (hb, zero), (1, L.REF_F))[0]
+    def update_ref(old_row, directive, vis, comp):
+        """The served bank's refresh row after this step.
+
+        ``old_row`` is the row before it, fields last (``[REF_F]``, or
+        ``[B, REF_F]`` with ``directive``/``vis``/``comp`` as [B] lane
+        vectors); returns the new row in the same shape.
+        """
         if refresh_mode == 4:
             # DARP rows advance unconditionally: the deadline ladder and the
             # debt carry even when no refresh was performed this step.
             shadow_end = jnp.where(directive["shadow"], comp + t.t_rfc_pb, 0)
-            busy = jnp.maximum(old_row[L.REF_BUSY_UNTIL],
+            busy = jnp.maximum(old_row[..., L.REF_BUSY_UNTIL],
                                jnp.maximum(
                                    jnp.where(directive["act"],
                                              directive["end"], 0),
                                    shadow_end))
-            row_new = jnp.stack([
-                directive["due"], busy, zero, directive["debt"],
-                jnp.maximum(old_row[L.REF_LAST_END], comp)])
-        else:
-            served_row = jnp.stack([
-                jnp.maximum(directive["due"] + t.t_refi, vis),
-                directive["end"], directive["target"],
-                old_row[L.REF_DEBT], old_row[L.REF_LAST_END]])
-            row_new = jnp.where(directive["pending"], served_row, old_row)
-        return jax.lax.dynamic_update_slice(ref, row_new[None], (hb, zero))
+            return jnp.stack([
+                directive["due"], busy,
+                jnp.broadcast_to(zero, jnp.shape(busy)), directive["debt"],
+                jnp.maximum(old_row[..., L.REF_LAST_END], comp)], axis=-1)
+        served_row = jnp.stack([
+            jnp.maximum(directive["due"] + t.t_refi, vis),
+            directive["end"], directive["target"],
+            old_row[..., L.REF_DEBT], old_row[..., L.REF_LAST_END]], axis=-1)
+        pending = directive["pending"]
+        if jnp.ndim(pending):
+            pending = pending[:, None]       # [B] lanes gate [B, REF_F] rows
+        return jnp.where(pending, served_row, old_row)
 
     def ref_cmds(directive, hb, comp):
         """[R, CMD_F] OP_REF slots for the served step (emit_commands only).
@@ -342,8 +349,10 @@ def _build_step1(policy: int, t: DramTiming, refresh_mode: int,
             vis = jnp.maximum(state["vis_prev"] + hgap,
                               jnp.maximum(jnp.where(hdep, comp_prev, 0),
                                           rob_lim))
-            vis, directive = head_visibility(state.get("ref"), vis, hb, hs,
-                                             hwr)
+            if refresh_mode:
+                ref_row = jax.lax.dynamic_slice(state["ref"], (hb, zero),
+                                                (1, L.REF_F))[0]
+                vis, directive = head_visibility(ref_row, vis, hs, hwr)
             req = dict(bank=hb, subarray=hs, row=hw, is_write=hwr, vis=vis)
             if refresh_mode:
                 req["ref_pending"] = directive["pending"]
@@ -356,8 +365,9 @@ def _build_step1(policy: int, t: DramTiming, refresh_mode: int,
             new = dict(state)
             new["bank"] = new_bank
             if refresh_mode:
-                new["ref"] = update_ref(state["ref"], directive, hb, vis,
-                                        comp)
+                row_new = update_ref(ref_row, directive, vis, comp)
+                new["ref"] = jax.lax.dynamic_update_slice(
+                    state["ref"], row_new[None], (hb, zero))
             new["ring"] = ring.at[i % _RING].set(comp)
             new["vis_prev"] = vis
             new["max_comp"] = jnp.maximum(state["max_comp"], comp)
@@ -419,8 +429,10 @@ def _build_stepC(policy: int, scheduler: int, t: DramTiming,
                           jnp.maximum(
                               jnp.where(h[:, L.RQ_DEP] != 0, comp_prev, 0),
                               rob_lim))
-        vis, directive = head_visibility(state.get("ref"), vis, hb, hs,
-                                         h[:, L.RQ_WR] != 0)
+        if refresh_mode:
+            vis, directive = head_visibility(
+                jnp.moveaxis(state["ref"][hb], -1, 0), vis, hs,
+                h[:, L.RQ_WR] != 0)
 
         # ---- scheduler: key the live heads, serve the argmin.
         # Under DARP the scheduler is refresh-aware: a bank one postpone
@@ -467,8 +479,12 @@ def _build_stepC(policy: int, scheduler: int, t: DramTiming,
         new = dict(state)
         new["bank"] = new_bank
         if refresh_mode:
-            new["ref"] = update_ref(state["ref"], directive_c, hc[L.RQ_BANK],
-                                    vis_c, comp)
+            hb_c = hc[L.RQ_BANK]
+            old_row = jax.lax.dynamic_slice(state["ref"], (hb_c, zero),
+                                            (1, L.REF_F))[0]
+            row_new = update_ref(old_row, directive_c, vis_c, comp)
+            new["ref"] = jax.lax.dynamic_update_slice(
+                state["ref"], row_new[None], (hb_c, zero))
         # pc + 1 == ptr[c] + 1: the scan runs exactly C*N steps over C*N
         # requests, so argmin always lands on a live core (dead keys are
         # _DEAD) and the chosen ptr is never clamped by the min() above.
@@ -563,12 +579,14 @@ def _simulate_controller(policy: int, scheduler: int, n_banks: int,
 
 @functools.partial(jax.jit, static_argnames=("policy", "n_banks",
                                              "n_subarrays", "timing",
-                                             "mlp_static", "unroll"))
+                                             "mlp_static", "refresh_mode",
+                                             "unroll"))
 def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
                             timing: DramTiming,
                             bank, subarray, row, is_write, gap, dep,  # [B, N]
                             mlp_window,                               # [B]
                             mlp_static: int | None = None,
+                            refresh_mode: int = 0,
                             unroll: int = _LANES_UNROLL):
     """Lane-vectorized batched single-core controller (ONE scan, B lanes).
 
@@ -601,8 +619,26 @@ def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
       a cross-lane gather on the read only. The ``i - 1`` ring read of the
       reference is carried directly as ``comp_last`` either way.
 
-    Eligibility is the fast-path configuration set (refresh off, open-row
-    policy, no command emission); ``engine.simulate_stacked`` dispatches
+    **Refresh** (``refresh_mode != 0``, static): a pending refresh closes
+    every row of the bank (bank-granular modes 1, 3, 4) or the refreshed
+    subarray's (modes 2, 5), so a step may change more than three rows.
+    The bank's refresh row then rides in the plane as row ``ns + 1``
+    (``REF_F == SA_F``), and each step gathers the served bank's whole
+    ``[B, ns + 2, SA_F]`` block with ONE gather, gates the heads with
+    :func:`_refresh_fns`' ``head_visibility``, runs
+    :func:`engine._step_math` (vmapped over the lanes) on the block, commits
+    the refresh row with ``update_ref``, and writes the block back with ONE
+    unique-indices scatter (a ``(lane, bank)`` pair is unique per step).
+    The closures and the block math are the ones ``_simulate_controller``
+    and the Pallas kernels run, and the counters accumulate in the block
+    math's ``[B, SC_F]`` scalar pack as they do there. (A row-wise variant
+    on :func:`engine._step_math_lanes` with the closure as a mask measured
+    12.0 us a step on a TPU v5e against this one's 8.6, MASA, 32 lanes,
+    DARP.) With refresh off none of this is traced: the program is the
+    three-row one above.
+
+    Eligibility is ``engine.runs_lanes`` (open-row policy, no command
+    emission, the scan backend); ``engine.simulate_stacked`` dispatches
     here and falls back to the vmapped general path otherwise. The C == 1
     scheduler degeneration applies per lane (program order), so no
     scheduler argument. Bit-identical to the vmapped path — the stacked
@@ -616,8 +652,17 @@ def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
     zero = jnp.int32(0)
     base = _engine._bank_state0(n_banks, ns)
     uniform = mlp_static is not None
+    plane0 = base["sa"]
+    if refresh_mode:
+        head_visibility, update_ref, _ = _refresh_fns(policy, t, ns,
+                                                      refresh_mode, False)
+        step_math = jax.vmap(functools.partial(_engine._step_math, policy, t,
+                                               refresh_mode))
+        # the refresh table rides in the plane as row ns + 1
+        plane0 = jnp.concatenate(
+            [plane0, _refresh_table0(n_banks, t, refresh_mode)[:, None]], 1)
     state0 = dict(
-        sa=jnp.broadcast_to(base["sa"], (B, n_banks, ns + 1, L.SA_F)),
+        sa=jnp.broadcast_to(plane0, (B, n_banks) + plane0.shape[1:]),
         act_hist=jnp.zeros((B, 4), jnp.int32),
         col=dict(col_last=jnp.full((B,), -(10 ** 6), jnp.int32),
                  col_last_wr=jnp.zeros((B,), bool),
@@ -627,6 +672,10 @@ def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
         comp_last=jnp.zeros((B,), jnp.int32),
         vis_prev=jnp.zeros((B,), jnp.int32),
     )
+    if refresh_mode:
+        # the block math keeps the channel scalars and the counters
+        state0.pop("col")
+        state0["sc"] = jnp.broadcast_to(base["scalars"], (B, L.SC_F))
     mlp = jnp.asarray(mlp_window, jnp.int32)
     i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
     # ONE packed [N, B, XS_F - 1] request tensor (XS_BANK..XS_DEP order,
@@ -640,8 +689,8 @@ def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
     # (one [B, YS_F] stack per step -> one buffer write instead of six)
     Y_TCOL, Y_COMP, Y_VIS, Y_HIT, Y_PREOWN, Y_EXTRA, YS_F = range(7)
 
-    def step(state, x):
-        i, xrow = x
+    def visible(state, i, xrow):
+        """The step's request fields and their visibility cycles, [B]."""
         hb, hs, hw = xrow[:, 0], xrow[:, 1], xrow[:, 2]
         hwr, hgap, hdep = xrow[:, 3] != 0, xrow[:, 4], xrow[:, 5]
         ring = state["ring"]
@@ -656,6 +705,12 @@ def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
                           jnp.maximum(jnp.where(hdep != 0,
                                                 state["comp_last"], 0),
                                       rob_lim))
+        return hb, hs, hw, hwr, vis
+
+    def step(state, x):
+        i, xrow = x
+        hb, hs, hw, hwr, vis = visible(state, i, xrow)
+        ring = state["ring"]
         sa = state["sa"]
         if is_masa:
             # no cross-subarray PRE under MASA: the two touched rows (own
@@ -700,6 +755,33 @@ def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
         y = jnp.stack([flags["t_col"], comp, vis, i32(flags["hit"]),
                        i32(flags["pre_own"]), i32(extra)], axis=1)
         return new, y
+
+    def step_refresh(state, x):
+        i, xrow = x
+        hb, hs, hw, hwr, vis = visible(state, i, xrow)
+        sa = state["sa"]
+        blk = sa[lanes, hb]                          # [B, ns + 2, SA_F]
+        ref_row = blk[:, ns + 1]
+        vis, directive = head_visibility(jnp.moveaxis(ref_row, -1, 0), vis,
+                                         hs, hwr)
+        req = dict(bank=hb, subarray=hs, row=hw, is_write=hwr, vis=vis,
+                   ref_pending=directive["pending"],
+                   ref_target=directive.get("target", jnp.zeros_like(hs)))
+        bk, act_hist, sc, comp = step_math(blk[:, :ns + 1], state["act_hist"],
+                                           state["sc"], req)
+        blk = jnp.concatenate(
+            [bk, update_ref(ref_row, directive, vis, comp)[:, None]], 1)
+        sa = sa.at[lanes, hb].set(blk, mode="promise_in_bounds",
+                                  unique_indices=True)
+        ring = jax.lax.dynamic_update_slice(state["ring"], comp[None],
+                                            (i % _RING, zero))
+        return dict(sa=sa, act_hist=act_hist, sc=sc, ring=ring,
+                    comp_last=comp, vis_prev=vis), None
+
+    if refresh_mode:
+        final, _ = jax.lax.scan(step_refresh, state0, xs, unroll=unroll)
+        return jax.vmap(functools.partial(_engine.result_from_state, N))(
+            final["sc"], final["vis_prev"])
 
     final, ys = jax.lax.scan(step, state0, xs, unroll=unroll)  # ys [N, B, YS_F]
 

@@ -303,7 +303,9 @@ def _step_math(policy: int, t: DramTiming, refresh_mode: int,
     * the Pallas kernel (:mod:`repro.core.dram.pallas_step`) calls it on a
       block sliced from the kernel-resident state, per grid lane;
     * the lane-vectorized batched scan (``controller._simulate_stacked_lanes``)
-      cross-checks its row-wise reformulation against ``jax.vmap`` of this.
+      runs ``jax.vmap`` of it on the gathered blocks when refresh is on,
+      and with refresh off cross-checks its row-wise reformulation
+      (:func:`_step_math_lanes`) against ``jax.vmap`` of this.
     """
     b, s, w = req["bank"], req["subarray"], req["row"]
     is_wr, vis = req["is_write"], req["vis"]
@@ -577,8 +579,10 @@ def _step_math_lanes(policy: int, t: DramTiming, own, oth, bv, act_hist, col,
                      req: dict):
     """Row-wise, lane-batched reformulation of :func:`_step_math`.
 
-    Fast-path configurations only: refresh off, open-row policy, no command
-    emission. Under those, one step can change exactly three rows of the
+    The lanes scan's step with refresh off (open-row policy, no command
+    emission; with refresh on a pending refresh closes more rows, and the
+    lanes scan runs :func:`_step_math` on the whole gathered block).
+    There one step can change exactly three rows of the
     packed plane — the request's own subarray ``s``, the previously open
     subarray ``so`` (non-MASA precharge coupling), and the bank-vector row —
     so instead of masked ``[ns + 1]`` column vectors over the whole gathered
@@ -771,15 +775,26 @@ def simulate(trace: Trace, policy: Policy, config: SimConfig = SimConfig()) -> S
     return res
 
 
+def runs_lanes(config: SimConfig) -> bool:
+    """Whether :func:`simulate_stacked` serves a batch under ``config`` with
+    the lane-batched scan (``controller._simulate_stacked_lanes``): the scan
+    backend, open rows and no command emission, under any refresh policy.
+    Closed-row and command-export batches take ``vmap`` of the per-trace
+    controller instead."""
+    return (config.backend == "scan" and config.row_policy == "open"
+            and not config.emit_commands)
+
+
 def simulate_stacked(stacked: dict, policy: Policy,
                      config: SimConfig = SimConfig()) -> SimResult:
-    """Batched entry point: vmap the simulator over pre-stacked [B, N] arrays.
+    """Batched entry point: simulate pre-stacked [B, N] arrays in one program.
 
     ``stacked`` is the dict produced by :func:`repro.core.dram.trace.stack_traces`
     (fields ``bank/subarray/row/is_write/gap/dep`` of shape [B, N] and
     ``mlp_window`` of shape [B]). All B rows share one compiled program — this
     is the primitive the experiment-sweep subsystem buckets cells onto. Each
-    row is one single-core controller instance.
+    row is one single-core controller instance: the lane-batched scan where
+    :func:`runs_lanes` says so, else ``vmap`` of the per-trace controller.
     """
     from repro.core.dram import controller
 
@@ -805,12 +820,11 @@ def simulate_stacked(stacked: dict, policy: Policy,
             closed_row=config.row_policy == "closed",
             interpret=config.backend == "pallas-interpret")
         return res
-    if (config.refresh_mode == 0 and config.row_policy == "open"
-            and not config.emit_commands):
-        # lane-vectorized single-scan fast path (bit-identical; see
-        # controller._simulate_stacked_lanes for the eligibility contract).
-        # A batch-uniform mlp_window (the common case) is promoted to a
-        # static scalar so the completion ring becomes contiguous slices.
+    if runs_lanes(config):
+        # lane-vectorized single-scan fast path, refresh included
+        # (bit-identical; see controller._simulate_stacked_lanes). A
+        # batch-uniform mlp_window is promoted to a static scalar so the
+        # completion ring's ROB read becomes a contiguous slice.
         import numpy as np
         mw = np.asarray(stacked["mlp_window"])
         mlp_static = int(mw.flat[0]) if (mw == mw.flat[0]).all() else None
@@ -820,7 +834,7 @@ def simulate_stacked(stacked: dict, policy: Policy,
             jnp.asarray(stacked["row"]), jnp.asarray(stacked["is_write"]),
             jnp.asarray(stacked["gap"]), jnp.asarray(stacked["dep"]),
             jnp.asarray(stacked["mlp_window"], jnp.int32),
-            mlp_static=mlp_static)
+            mlp_static=mlp_static, refresh_mode=config.refresh_mode)
     fn = functools.partial(controller._simulate_controller, eff, sched, nb, ns,
                            config.timing, config.refresh_mode,
                            closed_row=config.row_policy == "closed")

@@ -378,6 +378,7 @@ def run_sweep(grid: SweepGrid, cache: ResultCache | None = None, *,
             watchdog=StepWatchdog(threshold=resilience.straggler_threshold))
 
     quarantined = [q_record(q) for q in report.quarantined]
+    stranded = {q.index for q in report.quarantined}
     results = [
         CellResult(workload=c.workload, policy=c.policy, config=c.config,
                    overrides=c.override_dict, key=k, cache_hit=k in hit_keys,
@@ -392,6 +393,10 @@ def run_sweep(grid: SweepGrid, cache: ResultCache | None = None, *,
         # produced counters, so they don't count as simulated
         "simulated_cells": (sum(len(v) for v in pending.values())
                             - len(report.quarantined)),
+        # of those, the cells whose bucket ran the lane-batched scan
+        "lane_cells": sum(1 for idxs in pending.values() for i in idxs
+                          if i not in stranded
+                          and engine.runs_lanes(cells[i].config)),
         "sim_batches": report.n_batches,
         "quarantined_cells": len(cells) - len(results),
         **report.stats(),

@@ -97,6 +97,23 @@ class TestBucketingAndCache:
         for a, b in zip(s1.cells, s2.cells):
             assert a.counters == b.counters
 
+    def test_lane_cells_count_lane_buckets(self):
+        """``stats["lane_cells"]`` counts the simulated cells whose bucket
+        ran the lane-batched scan: open-row buckets, refresh on or off, and
+        not closed-row ones; cache hits are not simulated."""
+        grid = small_grid(
+            config_axes={"refresh_policy": ("none", "darp"),
+                         "row_policy": ("open", "closed")})
+        cache = ResultCache()
+        sweep = run_sweep(grid, cache)
+        per_point = len(WLS) * 3
+        assert sweep.stats["simulated_cells"] == 4 * per_point
+        assert sweep.stats["lane_cells"] == 2 * per_point
+        again = run_sweep(small_grid(
+            config_axes={"refresh_policy": ("darp", "sarp")}), cache)
+        assert again.stats["simulated_cells"] == per_point   # sarp only
+        assert again.stats["lane_cells"] == per_point
+
     def test_baseline_simulated_once_across_policy_comparisons(self):
         """The old sens_subarrays bug: baseline recomputed inside every gain()
         call, once per mechanism policy. With the cache, two back-to-back

@@ -167,8 +167,24 @@ class TestFixtureShape:
 # Stacked/batched path == per-trace loop, bit-for-bit.
 # --------------------------------------------------------------------------
 
+#: The refresh ladder with its deadlines inside the stacked-parity traces:
+#: every mode under REF_TIMING (CONFIGS' "all_bank" / "dsarp" cells keep the
+#: default tREFI the golden fixture was captured with).
+LADDER = {
+    "all_bank_ref": dict(refresh_policy="all_bank", timing=REF_TIMING),
+    "dsarp_ref": dict(refresh_policy="dsarp", timing=REF_TIMING),
+    "per_bank": CONFIGS["per_bank"], "darp": CONFIGS["darp"],
+    "sarp": CONFIGS["sarp"],
+}
+PARITY_CONFIGS = {**CONFIGS, **LADDER}
+
+#: Length of the ladder cases' traces (:func:`ladder_trace`).
+LADDER_N = 512
+
 # Bounded combo list so the parity tests reuse a handful of compiled
 # programs instead of compiling per example (trace length is fixed too).
+# Every refresh mode runs under BASELINE, SALP2, MASA and IDEAL: open-row
+# refresh batches take the lane-batched scan (engine.runs_lanes).
 COMBOS = [
     (Policy.BASELINE, "default"), (Policy.SALP2, "default"),
     (Policy.MASA, "default"), (Policy.IDEAL, "default"),
@@ -177,14 +193,57 @@ COMBOS = [
     (Policy.MASA, "per_bank"), (Policy.MASA, "darp"),
     (Policy.SALP2, "sarp"),
 ]
+COMBOS += [(p, c) for c in LADDER
+           for p in (Policy.BASELINE, Policy.SALP2, Policy.MASA, Policy.IDEAL)
+           if (p, c) not in COMBOS]
+
+#: Per-lane windows of the cases whose lanes differ in ``mlp_window``: the
+#: lanes scan then reads the completion ring with a cross-lane gather
+#: instead of a static slice.
+MIXED_MLP = (3, 7, 12)
+
+#: (policy, config, mlp) cases of the deterministic parity test: every combo
+#: with one shared window, and one case per refresh mode with MIXED_MLP.
+CASES = ([(p, c, 4) for p, c in COMBOS]
+         + [(Policy.MASA, c, MIXED_MLP) for c in LADDER])
+
+
+def ladder_trace(seed: int, n: int = LADDER_N,
+                 mlp: int | None = None) -> Trace:
+    """:func:`random_trace` with a hot middle half under REF_TIMING.
+
+    The middle half goes back to back (gap 0, no dependences) to two rows
+    of one subarray in turn, so every request is a row conflict: its first
+    five eighths are reads, and DARP's debt outgrows the postpone window
+    and forces bursts; its writes then carry write-shadow refreshes. The
+    random quarters around it leave idle gaps that the idle drain fills.
+    :func:`test_ladder_traces_fire_every_chain` checks that all three
+    chains fire.
+    """
+    tr = random_trace(seed, n=n, mlp=mlp)
+    a, b, c = n // 4, 5 * n // 8, 3 * n // 4
+    bank, sa, row = tr.bank.copy(), tr.subarray.copy(), tr.row.copy()
+    gap, wr, dep = tr.gap.copy(), tr.is_write.copy(), tr.dep.copy()
+    bank[a:c], sa[a:c], gap[a:c], dep[a:c] = bank[a], sa[a], 0, False
+    row[a:c] = row[a] + np.arange(c - a) % 2
+    wr[a:b] = False
+    return dataclasses.replace(tr, bank=bank, subarray=sa, row=row, gap=gap,
+                               is_write=wr, dep=dep)
+
+
+def _parity_traces(seed: int, cfg_name: str, mlp) -> list[Trace]:
+    """Three equal-length traces: one compiled program per case."""
+    if cfg_name in LADDER:
+        mlps = mlp if isinstance(mlp, tuple) else (mlp,) * 3
+        return [ladder_trace(seed + i, mlp=m) for i, m in enumerate(mlps)]
+    return [random_trace(seed + i, n=64, mlp=mlp) for i in range(3)]
 
 
 def _assert_stacked_matches(seed: int, policy: Policy, cfg_name: str,
-                            mlp: int, backend: str = "scan") -> None:
-    cfg = SimConfig(backend=backend, **CONFIGS[cfg_name])
-    ref_cfg = SimConfig(**CONFIGS[cfg_name])   # per-trace reference: scan
-    # equal-length traces with one shared mlp_window: one compiled program
-    traces = [random_trace(seed + i, n=64, mlp=mlp) for i in range(3)]
+                            mlp, backend: str = "scan") -> None:
+    cfg = SimConfig(backend=backend, **PARITY_CONFIGS[cfg_name])
+    ref_cfg = SimConfig(**PARITY_CONFIGS[cfg_name])   # per-trace reference
+    traces = _parity_traces(seed, cfg_name, mlp)
     stacked = simulate_stacked(stack_traces(traces), policy, cfg)
     for i, tr in enumerate(traces):
         ref = counters(simulate(tr, policy, ref_cfg))
@@ -193,14 +252,87 @@ def _assert_stacked_matches(seed: int, policy: Policy, cfg_name: str,
         assert got == ref, (policy, cfg_name, backend, i)
 
 
+def _case_id(case) -> str:
+    policy, cfg_name, mlp = case
+    tail = "" if mlp == 4 else "-mlp" + ".".join(map(str, mlp))
+    return f"{policy.name}-{cfg_name}{tail}"
+
+
 @pytest.mark.parametrize("backend", BACKENDS)
-@pytest.mark.parametrize("combo", COMBOS,
-                         ids=[f"{p.name}-{c}" for p, c in COMBOS])
+@pytest.mark.parametrize("combo", CASES, ids=[_case_id(c) for c in CASES])
 def test_stacked_equals_per_trace_simulate(combo, backend):
     """Deterministic stacked-vs-loop parity (runs without hypothesis)."""
-    policy, cfg_name = combo
-    _assert_stacked_matches(seed=1000 + COMBOS.index(combo), policy=policy,
-                            cfg_name=cfg_name, mlp=4, backend=backend)
+    policy, cfg_name, mlp = combo
+    _assert_stacked_matches(seed=1000 + CASES.index(combo), policy=policy,
+                            cfg_name=cfg_name, mlp=mlp, backend=backend)
+
+
+@pytest.mark.parametrize("combo", [c for c in CASES if c[1] == "darp"],
+                         ids=_case_id)
+def test_ladder_traces_fire_every_chain(combo):
+    """The DARP parity traces exercise all of DARP: decoding the first
+    lane's per-trace command export finds an idle-drain, a forced and a
+    write-shadow refresh chain."""
+    import jax.numpy as jnp
+
+    from repro.core.dram import controller, state_layout as L
+    from repro.core.dram.engine import _controller_args
+    from repro.core.dram.trace import to_ideal
+
+    policy, cfg_name, mlp = combo
+    cfg = SimConfig(emit_commands=True, **PARITY_CONFIGS[cfg_name])
+    tr = _parity_traces(1000 + CASES.index(combo), cfg_name, mlp)[0]
+    if policy == Policy.IDEAL:
+        tr = to_ideal(tr, cfg.n_banks, cfg.n_subarrays)
+    eff, sched, nb, ns = _controller_args(policy, cfg)
+    _, _, ys = controller._simulate_controller(
+        eff, sched, nb, ns, cfg.timing, cfg.refresh_mode,
+        *(jnp.asarray(getattr(tr, f))[None]
+          for f in ("bank", "subarray", "row", "is_write", "gap", "dep")),
+        jnp.asarray([tr.mlp_window], jnp.int32), jnp.zeros((1,), jnp.int32),
+        emit_commands=True)
+    # DARP's three REF slots close each step's log: idle drain, forced
+    # overflow, write shadow (controller._refresh_fns' ref_cmds)
+    fired = (np.asarray(ys["cmds"])[:, -3:, L.CMD_OP] == L.OP_REF).sum(0)
+    assert (fired > 0).all(), dict(zip(("idle", "forced", "shadow"),
+                                       fired.tolist()))
+
+
+ROUTES = [(dict(), True)]
+ROUTES += [(dict(refresh_policy=rp), True)
+           for rp in ("all_bank", "dsarp", "per_bank", "darp", "sarp")]
+ROUTES += [(dict(refresh_policy="darp", row_policy="closed"), False),
+           (dict(row_policy="closed"), False),
+           (dict(refresh_policy="darp", emit_commands=True), False),
+           (dict(emit_commands=True), False)]
+
+
+@pytest.mark.parametrize(
+    "cfg_kw, lanes", ROUTES,
+    ids=["-".join(f"{k}={v}" for k, v in kw.items()) or "default"
+         for kw, _ in ROUTES])
+def test_runs_lanes_routes_batches(monkeypatch, cfg_kw, lanes):
+    """``engine.runs_lanes`` decides the path: open-row batches without
+    command export take the lane-batched scan under every refresh policy;
+    closed-row and command-export batches take vmap of the per-trace
+    controller."""
+    from repro.core.dram import controller, engine
+
+    cfg = SimConfig(**cfg_kw)
+    assert engine.runs_lanes(cfg) is lanes
+    taken = []
+    for name in ("_simulate_stacked_lanes", "_simulate_controller"):
+        real = getattr(controller, name)
+
+        def spy(*a, _real=real, _name=name, **k):
+            taken.append(_name)
+            return _real(*a, **k)
+
+        monkeypatch.setattr(controller, name, spy)
+    simulate_stacked(stack_traces([random_trace(3, n=16),
+                                   random_trace(4, n=16)]), Policy.MASA, cfg)
+    assert set(taken) == {"_simulate_stacked_lanes" if lanes
+                          else "_simulate_controller"}
 
 
 def test_pallas_refuses_emit_commands():

@@ -199,6 +199,7 @@ class TestSweepQuarantine:
         assert sweep.stats["n_cells"] == 4 and len(sweep.cells) == 3
         assert sweep.stats["quarantined_cells"] == 1
         assert sweep.stats["simulated_cells"] == 3
+        assert sweep.stats["lane_cells"] == 3      # the stranded cell is not
         assert sweep.stats["bisections"] >= 1
         (q,) = sweep.quarantined
         assert q["workload"] == "mcf" and q["policy"] == "BASELINE"

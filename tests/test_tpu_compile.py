@@ -76,9 +76,24 @@ def test_lanes_scan_compiles(one_chip, policy):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("policy", [Policy.BASELINE, Policy.MASA],
+                         ids=lambda p: p.name)
+def test_lanes_scan_with_darp_compiles(one_chip, policy):
+    """Phase B's program: the lane-batched scan with DARP at 8 Gb,
+    per-lane ``mlp_window``s."""
+    cfg = SimConfig.for_tech("ddr3", density_gb=8, refresh_policy="darp")
+    eff, _, nb, ns = _controller_args(policy, cfg)
+    fields, mlp = _requests(one_chip, B)
+    compiled = controller._simulate_stacked_lanes.lower(
+        eff, nb, ns, cfg.timing, *fields, mlp,
+        refresh_mode=cfg.refresh_mode).compile()
+    _fits(compiled)
+
+
 def test_vmapped_refresh_controller_compiles(one_chip):
-    """Phase B's program: the per-trace controller scan with DARP refresh,
-    vmapped over the batch as ``engine.simulate_stacked`` does."""
+    """The per-trace controller scan with DARP refresh, vmapped over the
+    batch as ``engine.simulate_stacked`` still runs closed-row and
+    command-export batches."""
     cfg = SimConfig.for_tech("ddr3", density_gb=8, refresh_policy="darp")
     eff, sched, nb, ns = _controller_args(Policy.MASA, cfg)
     fn = functools.partial(controller._simulate_controller, eff, sched, nb,

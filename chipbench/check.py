@@ -2,26 +2,52 @@
 
 After the window has closed, a sample of the grid cells that its sweeps
 produced is drawn from the run's seed, over every sweep of the window, and
-each sampled cell is recomputed by :mod:`reference` from the cell's own
-data: its workload profile(s), the sweep's seed, the configuration's
-timing table. The reference regenerates the trace, serves it, and for a
-mix also the cores' run-alone baselines, so the comparison covers every
-layer a sweep passes through: trace generation, bucketing and stacking,
-the scan on the device, the readback into the cell's counters, and for
-mixes ``core_cycles``, ``alone_cycles`` and ``weighted_speedup``.
+each sampled cell is recomputed by the configuration's plain reference
+from the cell's own data: its workload profile(s), the sweep's seed, the
+configuration's timing table. The reference regenerates the trace, serves
+it, and for a mix also the cores' run-alone baselines, so the comparison
+covers every layer a sweep passes through: trace generation, bucketing and
+stacking, the scan on the device, the readback into the cell's counters
+(for a sharded sweep, the merge of its shards' fragments), and for mixes
+``core_cycles``, ``alone_cycles`` and ``weighted_speedup``.
 
 The number compared is ``mismatched_cells``: sampled cells whose integer
 counters (or, for a mix, per-core cycles, run-alone cycles or weighted
 speedup) differ in any way from the reference's, a cell the sweep never
 produced counting as one. The simulator's stated contract is
 bit-identity, so its limit is 0.
+
+A configuration names its reference with the key ``"reference":
+"<module>"``: the file ``<module>.py`` beside this one, loaded by path
+(:func:`reference_for`); without the key it is ``reference.py``. A
+reference module imports nothing of the program and gives, from plain
+Python data (the configuration with the traffic's overrides and the
+cell's axes as a ``dict``; workload profiles as the traffic file's
+objects):
+
+* ``generate_trace(profile, n, seed, cfg, row_space_offset=0)``: one
+  core's request stream, a ``dict`` of lists;
+* ``simulate(tr, policy, cfg, faw=True)``: the stream served under a
+  policy (by name), as ``{counter: int}`` named as the program's
+  ``SimResult`` fields;
+* ``simulate_mix(trs, mpkis, policy, scheduler, cfg, faw=True)``: several
+  cores' streams sharing the channel, as ``dict(counters=...,
+  core_cycles=..., alone_cycles=..., weighted_speedup=...)``.
+
+``faw=False`` drops the four-activate window (tFAW): the control, which
+has to read as mismatched.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
+from pathlib import Path
+
 import numpy as np
 
-import reference
 from sweeps import Cell, SweepRecord, cell_key
+
+HERE = Path(__file__).resolve().parent
 
 #: The compared number and its limit: an exact comparison.
 LIMITS = {"mismatched_cells": 0}
@@ -56,9 +82,28 @@ def draw_sample(cell: Cell, records: list[SweepRecord], seed: int,
                   for p in picks)
 
 
+@functools.cache
+def _load(path: Path):
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_reference_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference_for(config: dict):
+    """The plain reference a configuration names (``"reference"``), by
+    default ``reference.py``."""
+    name = config.get("reference", "reference")
+    if not name.isidentifier():
+        raise ValueError(f"reference {name!r} is not a module name")
+    return _load(HERE / f"{name}.py")
+
+
 def expected(cell: Cell, sweep_seed: int, prof, policy: str, ov: dict,
              faw: bool = True) -> dict:
     """The reference's results for one grid cell."""
+    reference = reference_for(cell.config)
     cfg = {**cell.sim_config(), **ov}
     n = cell.traffic["n_requests"]
     if not cell.is_mix:

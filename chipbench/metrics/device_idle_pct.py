@@ -1,7 +1,8 @@
 """Share of the traced window in which no operation ran on the device,
 percent: 100 * (1 - busy / window), from the profiler's trace
-(``xplane.reduce``). Until the runner has spans of its own, this stands for
-all the host work of a sweep: trace generation, stacking, readback."""
+(``xplane.reduce``). On more than one chip, busy is the mean of the chips'
+busy times, so this is the chips' mean idle share. The spans of the
+program's host layers say what the host did in that time."""
 
 
 def read(run):

@@ -1,7 +1,8 @@
 """Device busy nanoseconds per simulated request in the traced window: the
 union of device operation intervals over the requests of the sweeps the
-trace covers. Keyed on no program name, so it survives a change of the
-scan programs."""
+trace covers. On more than one chip, busy is the mean of the chips' busy
+times, so this is a chip's mean. Keyed on no program name, so it survives
+a change of the scan programs."""
 
 
 def read(run):

@@ -7,9 +7,11 @@ The lower reading: the program at the cell's own size, one sweep on each of
 ``--seeds`` seeds (``n``, ``n + 1``, ...) after one warm-up, each compared
 with the reference as a run compares its window. The upper reading: the
 control (``check.control``, the reference without tFAW) in the program's
-place on ``--control-seeds`` of those seeds. Prints one line per seed and a
-JSON summary last. Needs the chip, as a run does; the benchmark's runs
-never call this.
+place on ``--control-seeds`` of those seeds; both come from the reference
+the cell's configuration names (``check.reference_for``). A sharded cell
+compares its sweeps' merged fragments, as a run does. Prints one line per
+seed and a JSON summary last. Needs the chip, as a run does; the
+benchmark's runs never call this.
 """
 from __future__ import annotations
 
@@ -37,13 +39,13 @@ def main(argv=None) -> int:
     import sweeps
     cell = sweeps.load_cell(args.workload)
     try:
-        run.find_chips(cell.entry["chips"])
+        devices = run.find_chips(cell.entry["chips"])
     except run.NoChip as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return 2
     from repro import compile_cache
     compile_cache.enable()
-    program = sweeps.Program(cell)
+    program = sweeps.Program(cell, devices)
     program.sweep(sweeps.sweep_seed(args.seed, -1))
     lower, upper = [], []
     for k in range(args.seeds):

@@ -9,10 +9,11 @@ loads the cell's files and runs one warm-up sweep on a seed the window never
 uses, which compiles the cell's programs or loads them from the cache. The
 measured window then runs whole sweeps of the cell's grid back to back
 through the program's entry, each with a fresh seed and a fresh result
-cache, until ``--seconds`` have passed. ``--trace 1`` records the window
-with JAX's profiler, its programs compiled without per-operation trace
-points (``xplane.LIBTPU_TRACE_FLAGS``), and reports the per-layer metrics
-instead of the end-to-end ones.
+cache, until ``--seconds`` have passed; a traffic mix that names
+``shards`` shards every sweep over the cell's chips. ``--trace 1`` records
+the window with JAX's profiler, its programs compiled without
+per-operation trace points (``xplane.LIBTPU_TRACE_FLAGS``), and reports
+the per-layer metrics instead of the end-to-end ones.
 
 After the window closes, the device's peak memory is read, the program's
 state is let go, and a sample of the window's grid cells, drawn from the
@@ -127,7 +128,7 @@ def main(argv=None, *, chips=find_chips, shrink=None) -> int:
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
     # ---- set-up: the warm-up sweep compiles or loads every program
-    program = sweeps.Program(cell)
+    program = sweeps.Program(cell, devices)
     program.sweep(sweeps.sweep_seed(args.seed, -1))
     setup_s = time.perf_counter() - T_START
     setup_events = list(events)
@@ -162,7 +163,7 @@ def main(argv=None, *, chips=find_chips, shrink=None) -> int:
         try:
             reduced = xplane.reduce(
                 xplane.find(trace_dir), len(devices),
-                programs={r.index: r.stats["sim_batches"] for r in records})
+                programs={r.index: r.chip_programs for r in records})
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
 

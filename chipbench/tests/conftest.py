@@ -31,3 +31,13 @@ def shrink(cell, n_requests=240, units=3, sample=8):
 def cpu_chips(n):
     import jax
     return jax.devices("cpu")[:n]
+
+
+def window_of(cell, seed, n_sweeps=2):
+    """Records of a window of ``n_sweeps`` sweeps that produced no cells,
+    for a comparison whose results come from elsewhere."""
+    import sweeps
+    return [sweeps.SweepRecord(index=i, seed=sweeps.sweep_seed(seed, i),
+                               wall_s=0.0, n_cells=cell.n_cells, requests=0,
+                               stats={}, cells={}, quarantined=0)
+            for i in range(n_sweeps)]

@@ -4,16 +4,9 @@ import pytest
 
 import check
 import sweeps
-from conftest import SEEDS, shrink
+from conftest import SEEDS, shrink, window_of
 
 CELLS = ("ddr3_1core.fig4", "ddr3_4core.mixes", "ddr3_1core.darp8gb")
-
-
-def window_of(cell, seed, n_sweeps=2):
-    return [sweeps.SweepRecord(index=i, seed=sweeps.sweep_seed(seed, i),
-                               wall_s=0.0, n_cells=cell.n_cells, requests=0,
-                               stats={}, cells={}, quarantined=0)
-            for i in range(n_sweeps)]
 
 
 @pytest.mark.parametrize("seed", SEEDS)

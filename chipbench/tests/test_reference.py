@@ -1,18 +1,23 @@
 """The plain reference agrees with the program bit for bit at small sizes,
 on every policy, with refresh off and under DARP, and on mixes under
 FR-FCFS and TCM. (At the cells' sizes the benchmark compares them itself.)
+Each configuration's reference is the module it names, as ``check`` finds
+it.
 """
 import dataclasses
 import json
+from pathlib import Path
 
 import numpy as np
 import pytest
 
-import reference
-from conftest import BENCH, SEEDS
+import check
+import sweeps
+from conftest import BENCH, SEEDS, shrink, window_of
 
 CFG1 = json.loads((BENCH / "configs" / "ddr3_1066_1core.json").read_text())
 CFG4 = json.loads((BENCH / "configs" / "ddr3_1066_4core.json").read_text())
+REF1, REF4 = check.reference_for(CFG1), check.reference_for(CFG4)
 FIG4 = json.loads((BENCH / "traffic" / "fig4.json").read_text())
 MIXES = json.loads((BENCH / "traffic" / "mixes.json").read_text())
 N = 300
@@ -38,14 +43,14 @@ def test_single_core(index, refresh):
     seed = SEEDS[index % len(SEEDS)]
     tr = generate_trace(WorkloadProfile(**prof), N, seed=seed)
     cfg = {**CFG1, "refresh_policy": refresh}
-    rt = reference.generate_trace(prof, N, seed, cfg)
+    rt = REF1.generate_trace(prof, N, seed, cfg)
     for k in ("bank", "subarray", "row", "is_write", "gap", "dep"):
         assert getattr(tr, k).tolist() == rt[k], k
     assert tr.mlp_window == rt["mlp_window"]
     for pol in Policy:
         got = as_ints(simulate(tr, pol, program_config(
             CFG1, refresh_policy=refresh)))
-        assert got == reference.simulate(rt, pol.name, cfg), pol.name
+        assert got == REF1.simulate(rt, pol.name, cfg), pol.name
 
 
 @pytest.mark.parametrize("scheduler", ["FRFCFS", "TCM"])
@@ -60,15 +65,68 @@ def test_mix(mix, scheduler):
                           row_space_offset=stride * i)
            for i, p in enumerate(profs)]
     cfg = {**CFG4, "refresh_policy": "none"}
-    rts = [reference.generate_trace(p, N, seed, cfg,
+    rts = [REF4.generate_trace(p, N, seed, cfg,
                                     row_space_offset=stride * i)
            for i, p in enumerate(profs)]
     for pol in Policy:
         r = simulate_multicore(trs, pol, program_config(
             CFG4, scheduler=Scheduler[scheduler]))
-        want = reference.simulate_mix(rts, [p["mpki"] for p in profs],
+        want = REF4.simulate_mix(rts, [p["mpki"] for p in profs],
                                       pol.name, scheduler, cfg)
         assert as_ints(r.shared) == want["counters"], pol.name
         assert [int(x) for x in r.core_cycles] == want["core_cycles"]
         assert [float(x) for x in r.alone_cycles] == want["alone_cycles"]
         assert r.weighted_speedup == want["weighted_speedup"]
+
+
+#: A reference that serves every stream with the same counters.
+STUB = """
+def generate_trace(profile, n, seed, cfg, row_space_offset=0):
+    return {"name": profile["name"], "n": n}
+
+
+def simulate(tr, policy, cfg, faw=True):
+    return {"stub": tr["n"]}
+"""
+
+
+def small_fig4(**config):
+    cell = sweeps.load_cell("ddr3_1core.fig4")
+    cell = dataclasses.replace(cell, config={**cell.config, **config})
+    return shrink(cell, n_requests=60, units=2, sample=4)
+
+
+def test_a_configuration_names_its_reference(tmp_path, monkeypatch):
+    """A configuration with ``"reference": "<module>"`` is checked against
+    that module, loaded from beside ``check.py``."""
+    (tmp_path / "stub_reference.py").write_text(STUB)
+    monkeypatch.setattr(check, "HERE", tmp_path)
+    cell = small_fig4(reference="stub_reference")
+    assert Path(check.reference_for(cell.config).__file__) == \
+        tmp_path / "stub_reference.py"
+    readings = check.compare(cell, window_of(cell, SEEDS[0]), SEEDS[0],
+                             produce=lambda *_: {"counters": {"stub": 60}})
+    assert readings["sampled_cells"] == 4
+    assert readings["mismatched_cells"] == 0
+
+
+def test_without_the_key_the_reference_is_reference_py(monkeypatch):
+    cell = small_fig4()
+    assert "reference" not in cell.config
+    ref = check.reference_for(cell.config)
+    assert Path(ref.__file__) == BENCH / "reference.py"
+    calls = []
+
+    def simulate(tr, policy, cfg, faw=True):
+        calls.append(policy)
+        return {"stub": 60}
+
+    monkeypatch.setattr(ref, "simulate", simulate)
+    readings = check.compare(cell, window_of(cell, SEEDS[0]), SEEDS[0],
+                             produce=lambda *_: {"counters": {"stub": 60}})
+    assert len(calls) == 4 and readings["mismatched_cells"] == 0
+
+
+def test_a_reference_is_a_module_name():
+    with pytest.raises(ValueError, match="not a module name"):
+        check.reference_for({"reference": "../reference"})

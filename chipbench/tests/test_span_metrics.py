@@ -96,6 +96,6 @@ def test_every_reader_is_a_benchmark_metric():
         assert per_layer[name]["source"] == "program_span"
         assert per_layer[name]["moves"] == "sim_req_per_s"
     assert per_layer["cache_ns_per_req"]["workloads"] == [
-        "ddr3_1core.fig4", "ddr3_1core.darp8gb"]
+        "ddr3_1core.fig4", "ddr3_1core.darp8gb", "ddr3_1core.fig4_shard4"]
     assert per_layer["alone_baseline_ns_per_req"]["workloads"] == [
         "ddr3_4core.mixes"]

@@ -7,8 +7,11 @@ executions (``XLA Modules``) last 1,949,022 + 1,561,776 + 1,948,917 +
 1,561,510 ns and do not overlap; the sweep spans run from 42,468,371 ns to
 68,182,668 + 23,827,366 ns.
 """
+import types
+
 import pytest
 
+import run
 import xplane
 from conftest import HERE
 
@@ -61,3 +64,50 @@ def test_a_trace_that_lost_programs_is_refused():
 
 def test_union():
     assert xplane.union([(5, 9), (0, 3), (2, 4), (9, 10)]) == [(0, 4), (5, 10)]
+
+
+def test_busy_per_chip_on_one_chip(reduced):
+    assert reduced["busy_per_chip_s"] == [pytest.approx(BUSY_NS / 1e9,
+                                                        abs=1e-12)]
+
+
+def four_planes(overlapped: bool):
+    """One sweep, four shards of 200 us, one on each chip: one after
+    another in a 1 ms sweep, or all at once in a 300 us one."""
+    starts = (50_000,) * 4 if overlapped else (0, 250_000, 500_000, 750_000)
+    spans = [(f"{xplane.SWEEP_SPAN} 0", 0,
+              300_000 if overlapped else 1_000_000)]
+    device_ops = [[("jit__simulate_stacked_lanes(7)", t, t + 200_000)]
+                  for t in starts]
+    return xplane.reduce_events(spans, [], device_ops, 4, programs={0: 1})
+
+
+@pytest.mark.parametrize("overlapped", [False, True])
+def test_busy_per_chip_of_four_planes(overlapped):
+    reduced = four_planes(overlapped)
+    assert reduced["busy_per_chip_s"] == [pytest.approx(2e-4)] * 4
+    # busy_s stays the chips' mean
+    assert reduced["busy_s"] == pytest.approx(2e-4)
+    assert reduced["breakdown"]["device_ops"] == [
+        ["jit__simulate_stacked_lanes", pytest.approx(8e-4)]]
+
+
+def test_a_chip_that_lost_its_shard_is_refused():
+    """Each chip runs its shard of every bucket: a plane without it lost
+    events."""
+    spans = [(f"{xplane.SWEEP_SPAN} 0", 0, 1_000_000)]
+    device_ops = [[("p", 0, 10)]] * 3 + [[]]
+    with pytest.raises(RuntimeError, match="device plane 3 holds 0"):
+        xplane.reduce_events(spans, [], device_ops, 4, programs={0: 1})
+
+
+def busy_chips(reduced):
+    return run.load_reader("busy_chips")(types.SimpleNamespace(trace=reduced))
+
+
+def test_busy_chips_serial_shards_keep_to_one():
+    assert busy_chips(four_planes(overlapped=False)) == pytest.approx(0.8)
+
+
+def test_busy_chips_overlapped_shards_pass_one():
+    assert busy_chips(four_planes(overlapped=True)) == pytest.approx(8 / 3)

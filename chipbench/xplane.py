@@ -6,10 +6,10 @@ one's end. Within it:
 
 * **busy** — on each device plane (``/device:TPU:<n>``), the union of the
   intervals in which a program ran (events of the ``XLA Modules`` line:
-  one per execution of a jitted program), averaged over the devices. The
-  traced run's programs are compiled without per-operation trace points
-  (:data:`LIBTPU_TRACE_FLAGS`); where a trace has such events (``XLA
-  Ops``), they are not used;
+  one per execution of a jitted program): per plane, and averaged over
+  the devices. The traced run's programs are compiled without
+  per-operation trace points (:data:`LIBTPU_TRACE_FLAGS`); where a trace
+  has such events (``XLA Ops``), they are not used;
 * **device_ops** — the programs with the most device time, by name
   without the compile hash, summed over the devices;
 * **idle_gaps** — the gaps between busy intervals, each named by what the
@@ -96,23 +96,17 @@ def _events(line):
 
 def reduce(path: str, n_devices: int,
            programs: dict[int, int] | None = None) -> dict:
-    """``busy_s``, ``window_s`` and the ``breakdown`` of a traced window.
-
-    ``busy_s`` averages the device planes' busy time over ``n_devices``,
-    the chips the run used. ``programs``, where given, maps a sweep's index
-    to the programs it ran at the least (its batches); a device plane that
-    holds fewer executions inside that sweep's span has lost events (the
-    profiler's buffers were full), and the trace is refused rather than
-    read as idle time.
-    """
+    """``busy_s``, ``busy_per_chip_s``, ``window_s`` and the ``breakdown``
+    of a traced window, read from the trace at ``path``
+    (:func:`reduce_events` says what each is)."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
-    spans, host = [], []
-    device_ops: list[list[tuple[str, int, int]]] = []
+    spans, host, planes = [], [], []
     for plane in data.planes:
         if DEVICE_PLANE.match(plane.name):
-            device_ops.append([ev for ln in plane.lines if ln.name == MODULES
-                               for ev in _events(ln)])
+            planes.append((int(plane.name.rsplit(":", 1)[1]),
+                           [ev for ln in plane.lines if ln.name == MODULES
+                            for ev in _events(ln)]))
         elif plane.name.startswith("/host:"):
             # the host thread that ran the sweeps is the one that holds
             # their annotations
@@ -125,24 +119,45 @@ def reduce(path: str, n_devices: int,
                              if not ev[0].startswith(SWEEP_SPAN)]
     if not spans:
         raise RuntimeError(f"{path}: no {SWEEP_SPAN} annotation on the host")
-    if not device_ops:
+    if not planes:
         raise RuntimeError(f"{path}: no {DEVICE_PLANE.pattern} plane")
+    try:
+        return reduce_events(spans, host, [ops for _, ops in sorted(planes)],
+                             n_devices, programs)
+    except RuntimeError as e:
+        raise RuntimeError(f"{path}: {e}") from None
+
+
+def reduce_events(spans, host, device_ops, n_devices: int,
+                  programs: dict[int, int] | None = None) -> dict:
+    """The traced window's numbers from its events, each ``(name, start_ns,
+    end_ns)``: the sweep annotations ``spans``, the other ``host`` events
+    of their thread, and per device plane the program executions.
+
+    ``busy_per_chip_s`` is each device plane's busy time in the window, in
+    plane order; ``busy_s`` averages them over ``n_devices``, the chips the
+    run used. ``programs``, where given, maps a sweep's index to the
+    programs it ran at the least on each chip; a device plane that holds
+    fewer executions inside that sweep's span has lost events (the
+    profiler's buffers were full), and the trace is refused rather than
+    read as idle time.
+    """
     for name, s, e in spans:
         want = (programs or {}).get(int(name.split()[-1]), 0)
         for n, ops in enumerate(device_ops):
             got = sum(1 for _, t, _ in ops if s <= t < e)
             if got < want:
                 raise RuntimeError(
-                    f"{path}: device plane {n} holds {got} program "
-                    f"executions in {name}, which ran at least {want}: the "
-                    f"profiler dropped events")
+                    f"device plane {n} holds {got} program executions in "
+                    f"{name}, which ran at least {want}: the profiler "
+                    f"dropped events")
     spans = [(s, e) for _, s, e in spans]
     lo, hi = min(s for s, _ in spans), max(e for _, e in spans)
 
-    busy_ns, op_ns, idle = 0, {}, []
+    per_chip, op_ns, idle = [], {}, []
     for ops in device_ops:
         busy = union(clip([(s, e) for _, s, e in ops], lo, hi))
-        busy_ns += sum(e - s for s, e in busy)
+        per_chip.append(sum(e - s for s, e in busy))
         for name, s, e in ops:
             d = min(e, hi) - max(s, lo)
             if d > 0:
@@ -159,7 +174,9 @@ def reduce(path: str, n_devices: int,
         return [[k, v / 1e9] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:TOP]]
 
-    return {"busy_s": busy_ns / n_devices / 1e9, "window_s": (hi - lo) / 1e9,
+    return {"busy_s": sum(per_chip) / n_devices / 1e9,
+            "busy_per_chip_s": [b / 1e9 for b in per_chip],
+            "window_s": (hi - lo) / 1e9,
             "breakdown": {"device_ops": top(op_ns), "idle_gaps": top(gaps)}}
 
 

@@ -32,7 +32,8 @@ objects):
   ``SimResult`` fields;
 * ``simulate_mix(trs, mpkis, policy, scheduler, cfg, faw=True)``: several
   cores' streams sharing the channel, as ``dict(counters=...,
-  core_cycles=..., alone_cycles=..., weighted_speedup=...)``.
+  core_cycles=..., alone_cycles=..., weighted_speedup=...)``. Only a
+  configuration that a mix cell (``run_mix_sweep``) uses needs it.
 
 ``faw=False`` drops the four-activate window (tFAW): the control, which
 has to read as mismatched.

@@ -9,7 +9,7 @@ in a process of its own. Each case runs ``run.main`` on
 buckets of eight cells, two cells a shard), one sweep, every cell compared,
 with the look for a chip replaced by the four CPU devices. The faults break
 what the sweep produces: the batched simulation behind ``run_sweep``
-(``runner._SIMULATE``, as ``test_faults.py`` breaks it) or the fragments
+(``runner._SIMULATE``, as ``per_cell.py`` breaks it) or the fragments
 the shards stream (``runner.StreamingAggregator``): one shard's fragment
 left out of the merge (the exchange between chips lost), two shards'
 counters swapped, one fragment delivered twice. Prints one JSON line per
@@ -68,7 +68,7 @@ def main() -> int:
     import jax
     import run
     from repro.experiments import runner
-    from test_faults import single_fault
+    from per_cell import single_fault
 
     orig_simulate, orig_agg = runner._SIMULATE, runner.StreamingAggregator
     for case in CASES:

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 import check
+import per_cell
 import sweeps
 from conftest import BENCH, SEEDS, shrink, window_of
 
@@ -125,6 +126,42 @@ def test_without_the_key_the_reference_is_reference_py(monkeypatch):
     readings = check.compare(cell, window_of(cell, SEEDS[0]), SEEDS[0],
                              produce=lambda *_: {"counters": {"stub": 60}})
     assert len(calls) == 4 and readings["mismatched_cells"] == 0
+
+
+#: A single-core reference: the plain reference's trace generator and
+#: simulator, and no ``simulate_mix``.
+SINGLE_CORE = """
+import importlib.util
+
+_spec = importlib.util.spec_from_file_location("plain_reference", {path!r})
+_plain = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_plain)
+generate_trace, simulate = _plain.generate_trace, _plain.simulate
+"""
+
+
+def test_a_single_core_reference_needs_no_simulate_mix(tmp_path, capsys,
+                                                       monkeypatch):
+    """A reference without ``simulate_mix`` checks a ``run_sweep`` cell:
+    a run of a shrunk fig4 against it is correct, and its control is
+    not."""
+    (tmp_path / "single_core.py").write_text(
+        SINGLE_CORE.format(path=str(BENCH / "reference.py")))
+    monkeypatch.setattr(check, "HERE", tmp_path)
+
+    def single_core(cell):
+        return dataclasses.replace(
+            cell, config={**cell.config, "reference": "single_core"})
+
+    cell = single_core(sweeps.load_cell("ddr3_1core.fig4"))
+    assert not cell.is_mix
+    assert not hasattr(check.reference_for(cell.config), "simulate_mix")
+    result = per_cell.run_cell(
+        cell.name, capsys, SEEDS[2],
+        shrink_to=lambda c: shrink(single_core(c), units=3, sample=8))
+    assert result["correct"] is True
+    assert result["checks"]["mismatched_cells"] == {"value": 0, "limit": 0}
+    per_cell.check_control_fails(cell, SEEDS[2])
 
 
 def test_a_reference_is_a_module_name():

@@ -89,13 +89,42 @@ def test_layers_add_up_to_the_sweep():
     assert layers + unspanned == pytest.approx(0.010 * 1e9 / 1_000)
 
 
+BENCHMARK = sweeps.load_json(sweeps.ROOT / "BENCHMARK.json")
+ENTRIES = {w["name"]: w for w in BENCHMARK["workloads"]}
+
+
+def _entry_is(entry):
+    return sweeps.load_traffic(ENTRIES[entry]["traffic"])["entry"]
+
+
+#: What a cell needs for the metric to find something to read: the result
+#: cache and the lane counter exist only in ``run_sweep``, the run-alone
+#: baselines only in ``run_mix_sweep``, more than one busy chip only on
+#: more than one chip.
+LISTS_ONLY = {
+    "cache_ns_per_req": lambda w: _entry_is(w) == "run_sweep",
+    "lane_cells_pct": lambda w: _entry_is(w) == "run_sweep",
+    "alone_baseline_ns_per_req": lambda w: _entry_is(w) == "run_mix_sweep",
+    "busy_chips": lambda w: ENTRIES[w]["chips"] > 1,
+}
+
+
 def test_every_reader_is_a_benchmark_metric():
-    bench = sweeps.load_json(sweeps.ROOT / "BENCHMARK.json")
-    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    per_layer = {m["name"]: m for m in BENCHMARK["per_layer"]}
     for name in SPAN_METRICS:
         assert per_layer[name]["source"] == "program_span"
         assert per_layer[name]["moves"] == "sim_req_per_s"
-    assert per_layer["cache_ns_per_req"]["workloads"] == [
-        "ddr3_1core.fig4", "ddr3_1core.darp8gb", "ddr3_1core.fig4_shard4"]
-    assert per_layer["alone_baseline_ns_per_req"]["workloads"] == [
-        "ddr3_4core.mixes"]
+    # each rule of LISTS_ONLY is about a metric that lists its cells
+    assert all("workloads" in per_layer[name] for name in LISTS_ONLY)
+
+
+@pytest.mark.parametrize("metric", [m for m in BENCHMARK["per_layer"]
+                                    if "workloads" in m],
+                         ids=lambda m: m["name"])
+def test_a_metric_lists_only_cells_that_have_it(metric):
+    """A metric's ``workloads`` are cells of the benchmark, each one in
+    which the metric's reader finds something to read."""
+    assert metric["workloads"]
+    assert set(metric["workloads"]) <= set(ENTRIES)
+    fits = LISTS_ONLY.get(metric["name"], lambda w: True)
+    assert all(fits(w) for w in metric["workloads"]), metric["workloads"]

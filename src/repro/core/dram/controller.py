@@ -23,8 +23,12 @@ machine (:mod:`engine`) does not:
   parallelization), and every mode directs the timing layer to close the
   refreshed row(s).
 
-``engine.simulate*`` instantiates this scan with one core;
-``multicore.simulate_multicore*`` with C cores — there is exactly one
+``engine.simulate*`` instantiates this scan with one core and
+``commands.simulate_mix_commands`` with C cores;
+``multicore.simulate_multicore*`` run their mixes on the lanes of one C-core
+scan (:func:`_simulate_mixes_lanes`). Every executor calls the same step
+math (``engine._step_math``), scheduler keys (``schedulers.request_key``)
+and refresh closures (:func:`_refresh_fns`) — there is exactly one
 implementation of the shared-channel semantics.
 
 Scan carry (see :mod:`repro.core.dram.state_layout`): the engine's four
@@ -69,6 +73,9 @@ _SCAN_UNROLL = 1
 #: work per sequential dependency, so a 2-way unroll overlaps one step's
 #: scatter with the next step's gather math without blowing up code size —
 #: ~1.1-1.2x on batch32; unroll=4 regresses (see docs/performance.md).
+#: The lane-major mix scan (:func:`_simulate_mixes_lanes`) takes it too: on
+#: a TPU v5e a step of four 4-core mixes costs 11.76 us at 2 against 12.19
+#: at 1 (PERF.md).
 _LANES_UNROLL = 2
 
 
@@ -814,3 +821,170 @@ def _simulate_stacked_lanes(policy: int, n_banks: int, n_subarrays: int,
         sum_latency=jnp.sum(jnp.where(iw, 0, comp - vis), axis=0),
         n_reads=jnp.int32(N) - n_wr,
         sa_open_cycles=sa_open)
+
+
+@functools.partial(jax.jit, static_argnames=("policy", "scheduler", "n_banks",
+                                             "n_subarrays", "timing",
+                                             "refresh_mode", "closed_row",
+                                             "unroll"))
+def _simulate_mixes_lanes(policy: int, scheduler: int, n_banks: int,
+                          n_subarrays: int, timing: DramTiming,
+                          refresh_mode: int,
+                          bank, subarray, row, is_write, gap, dep,  # [M, C, N]
+                          mlp_window, rank,                         # [M, C]
+                          closed_row: bool = False,
+                          unroll: int = _LANES_UNROLL):
+    """Lane-major batched C-core controller (ONE scan, M mixes on lanes).
+
+    ``jax.vmap`` of :func:`_simulate_controller` over M mixes gives every
+    store of :func:`_build_stepC` (the bank block, the ``core`` row, the
+    completion-ring slot, the refresh row) a per-lane index, and XLA on a
+    TPU lowers those per-lane ``dynamic_update_slice``s as serial loops
+    over the lanes (31.9 us a step for four 4-core mixes on a TPU v5e,
+    11.8 here; PERF.md). This scan carries all M mixes side by side, as
+    :func:`_simulate_stacked_lanes` does for 1-core lanes with refresh on:
+    the plane ``[M, nb, ns + 1 (+1), SA_F]`` (the bank's refresh row rides
+    as row ``ns + 1``), the scalar packs ``[M, SC_F]`` and ``[M, 4]``, the
+    per-core ``[M, C, CORE_F]`` and the rings ``[M, C, _RING]``. A step:
+
+    * ONE gather of every lane's C head rows from the packed
+      ``[M, C, N, RQ_F]`` request tensor, then per-core visibility and the
+      refresh gate (:func:`_refresh_fns`' ``head_visibility``) as in
+      :func:`_build_stepC`;
+    * the scheduler keys (``schedulers.request_key``, vmapped over the
+      lanes), a per-lane ``argmin`` and ONE ``take_along_axis`` of the
+      chosen head's fields, bookkeeping and refresh directive;
+    * ONE gather of each lane's served bank block, ``jax.vmap`` of
+      :func:`engine._step_math` on it (the counters accumulate in its
+      scalar pack), ``update_ref`` on the refresh row, and ONE
+      unique-indices scatter of the block back (a ``(lane, bank)`` pair is
+      unique per step);
+    * the ``core`` row and the ring slot of the served core written by a
+      one-hot ``where`` over the C cores and the ring, not by per-lane
+      stores.
+
+    The step math, the scheduler keys and the refresh closures are the
+    ones ``_simulate_controller`` and the Pallas kernels run, so the
+    results are bit-identical to ``jax.vmap`` of it: every scheduler,
+    policy, refresh mode and row policy (tests/test_controller.py,
+    tests/test_packed_state.py). Returns ``(SimResult, core_cycles)`` with
+    a leading ``[M]`` axis, as the vmapped controller does; there is no
+    command emission here (``commands.simulate_mix_commands`` keeps the
+    per-mix controller).
+    """
+    t = timing
+    M, C, N = bank.shape
+    ns = n_subarrays
+    lanes = jnp.arange(M, dtype=jnp.int32)
+    cores = jnp.arange(C, dtype=jnp.int32)
+    slots = jnp.arange(_RING, dtype=jnp.int32)
+    head_visibility, update_ref, _ = _refresh_fns(policy, t, ns,
+                                                  refresh_mode, False)
+    step_math = jax.vmap(functools.partial(_engine._step_math, policy, t,
+                                           refresh_mode,
+                                           closed_row=closed_row))
+
+    def keys(sa, sc, hb, hs, hw, vis, rank_, live, hwr, ref_debt):
+        return request_key(scheduler, dict(sa=sa, scalars=sc), hb, hs, hw,
+                           vis, rank_, C, live, ref_debt=ref_debt,
+                           ref_urgent=t.ref_postpone_max - 1, hwr=hwr)
+
+    base = _engine._bank_state0(n_banks, ns)
+    plane0 = base["sa"]
+    if refresh_mode:
+        # the refresh table rides in the plane as row ns + 1
+        plane0 = jnp.concatenate(
+            [plane0, _refresh_table0(n_banks, t, refresh_mode)[:, None]], 1)
+    state0 = dict(
+        sa=jnp.broadcast_to(plane0, (M,) + plane0.shape),
+        sc=jnp.broadcast_to(base["scalars"], (M, L.SC_F)),
+        act_hist=jnp.zeros((M, 4), jnp.int32),
+        core=jnp.zeros((M, C, L.CORE_F), jnp.int32),
+        comp_ring=jnp.zeros((M, C, _RING), jnp.int32),
+    )
+    # ONE packed [M, C, N, RQ_F] request tensor
+    reqs = _pack_reqs(bank, subarray, row, is_write, gap, dep)
+    mlp = jnp.asarray(mlp_window, jnp.int32)
+
+    def step(state, _):
+        sa, core = state["sa"], state["core"]
+        ptr = core[..., L.CORE_PTR]                             # [M, C]
+        live = ptr < N
+        p = jnp.minimum(ptr, N - 1)
+        h = reqs[lanes[:, None], cores, p]                      # [M, C, RQ_F]
+        hb, hs, hw = h[..., L.RQ_BANK], h[..., L.RQ_SA], h[..., L.RQ_ROW]
+        hwr = h[..., L.RQ_WR] != 0
+
+        # ---- per-core visibility of the head request
+        ring_idx = jnp.stack([(p - 1) % _RING, (p - mlp) % _RING], axis=-1)
+        rd = state["comp_ring"][lanes[:, None, None], cores[:, None],
+                                ring_idx]                       # [M, C, 2]
+        rob_lim = jnp.where(p >= mlp, rd[..., 1], 0)
+        vis = jnp.maximum(core[..., L.CORE_VIS_PREV] + h[..., L.RQ_GAP],
+                          jnp.maximum(
+                              jnp.where(h[..., L.RQ_DEP] != 0, rd[..., 0], 0),
+                              rob_lim))
+        ext = [h]
+        ref_debt = None
+        if refresh_mode:
+            refb = sa[lanes[:, None], hb, ns + 1]               # [M, C, REF_F]
+            vis, directive = head_visibility(jnp.moveaxis(refb, -1, 0), vis,
+                                             hs, hwr)
+            if refresh_mode == 4:
+                ref_debt = refb[..., L.REF_DEBT]
+            dkeys = sorted(directive)
+
+        # ---- scheduler: key the live heads, serve each lane's argmin
+        key = jax.vmap(keys)(sa, state["sc"], hb, hs, hw, vis, rank, live,
+                             hwr, ref_debt)
+        c = jnp.argmin(key, axis=1).astype(jnp.int32)          # [M]
+
+        # ONE gather of the chosen heads' fields + step bookkeeping (lanes
+        # RQ_VIS / RQ_PTR / RQ_MAX_COMP after RQ_F) + refresh directive
+        ext += [vis[..., None], p[..., None],
+                core[..., L.CORE_MAX_COMP][..., None]]
+        if refresh_mode:
+            ext += [directive[k].astype(jnp.int32)[..., None] for k in dkeys]
+        hc = jnp.take_along_axis(jnp.concatenate(ext, axis=-1),
+                                 c[:, None, None], axis=1)[:, 0]
+        hb_c, vis_c = hc[:, L.RQ_BANK], hc[:, L.RQ_VIS]
+        pc, max_comp_c = hc[:, L.RQ_PTR], hc[:, L.RQ_MAX_COMP]
+        req = dict(bank=hb_c, subarray=hc[:, L.RQ_SA], row=hc[:, L.RQ_ROW],
+                   is_write=hc[:, L.RQ_WR] != 0, vis=vis_c)
+        if refresh_mode:
+            directive_c = {k: hc[:, L.RQ_EXT_F + j]
+                           for j, k in enumerate(dkeys)}
+            for k in ("pending", "shadow", "act"):
+                if k in directive_c:
+                    directive_c[k] = directive_c[k] != 0
+            req["ref_pending"] = directive_c["pending"]
+            req["ref_target"] = directive_c.get("target",
+                                                jnp.zeros_like(hb_c))
+
+        # ---- the served bank's block: one gather, the math, one scatter
+        blk = sa[lanes, hb_c]                    # [M, ns + 1 (+1), SA_F]
+        bk, act_hist, sc, comp = step_math(blk[:, :ns + 1], state["act_hist"],
+                                           state["sc"], req)
+        if refresh_mode:
+            bk = jnp.concatenate(
+                [bk, update_ref(blk[:, ns + 1], directive_c, vis_c,
+                                comp)[:, None]], 1)
+        sa = sa.at[lanes, hb_c].set(bk, mode="promise_in_bounds",
+                                    unique_indices=True)
+
+        # ---- the served core's row and ring slot: one-hot over C (and the
+        # ring). pc + 1 == ptr[c] + 1, as in _build_stepC.
+        served = cores == c[:, None]                            # [M, C]
+        core_row = jnp.stack([pc + 1, vis_c, jnp.maximum(max_comp_c, comp)],
+                             axis=-1)                           # [M, CORE_F]
+        core = jnp.where(served[..., None], core_row[:, None], core)
+        ring = jnp.where(served[..., None]
+                         & (slots == (pc % _RING)[:, None, None]),
+                         comp[:, None, None], state["comp_ring"])
+        return dict(sa=sa, sc=sc, act_hist=act_hist, core=core,
+                    comp_ring=ring), None
+
+    final, _ = jax.lax.scan(step, state0, None, length=C * N, unroll=unroll)
+    res = jax.vmap(functools.partial(_engine.result_from_state, C * N))(
+        final["sc"], final["core"][..., L.CORE_VIS_PREV])
+    return res, final["core"][..., L.CORE_MAX_COMP]

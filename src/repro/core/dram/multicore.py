@@ -10,17 +10,22 @@ scheduler combinations the paper evaluates on top of SALP. Refresh/DSARP and
 the closed-row policy apply here exactly as in single-core, via ``SimConfig``.
 
 The controller scan underneath runs on the packed state layout
-(:mod:`repro.core.dram.state_layout`); with C == 1 it takes a statically
-specialized fast path (serve order = program order, no scheduler argmin)
-that is bit-identical to the general path — the 1-core-mix ≡ ``simulate``
-assertions in tests/test_controller.py pin exactly that equivalence.
+(:mod:`repro.core.dram.state_layout`). On the scan backend a batch of M
+mixes — and a single mix, as M = 1 — runs ONE lane-major C-core scan
+(``controller._simulate_mixes_lanes``): the mixes ride side by side in the
+scan's carry, and each step gathers every lane's heads, keys them, serves
+each lane's ``argmin`` and moves each lane's served bank block with one
+gather and one scatter. It is bit-identical to ``jax.vmap`` of the per-mix
+controller (``controller._simulate_controller``, which ``simulate`` and
+command export still run) — tests/test_controller.py and
+tests/test_packed_state.py pin both against each other and the golden
+fixture.
 
 Metrics: weighted speedup = sum_i IPC_shared(i) / IPC_alone(i).
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -86,15 +91,27 @@ def alone_baseline_cycles(mixes: list[list[Trace]],
                       np.float64)
 
 
+def runs_mix_lanes(config: SimConfig) -> bool:
+    """Whether :func:`simulate_multicore_batch` serves a batch under
+    ``config`` with the lane-major C-core scan
+    (``controller._simulate_mixes_lanes``): the scan backend, under every
+    scheduler, refresh policy and row policy. The Pallas backends run the
+    fused mix kernel instead."""
+    return config.backend == "scan"
+
+
 def simulate_multicore_batch(mixes: list[list[Trace]], policy: Policy,
                              config: SimConfig = SimConfig(),
                              use_ranking: bool = False,
                              alone_cycles: np.ndarray | None = None,
                              ) -> list[MulticoreResult]:
-    """Batched entry point: vmap the shared-channel controller over M mixes.
+    """Batched entry point: simulate M mixes in one compiled program.
 
     All mixes must have the same core count and trace length; they share one
-    compiled program ([M, C, N] stacked arrays) instead of M sequential scans.
+    compiled program ([M, C, N] stacked arrays) instead of M sequential scans:
+    on the scan backend the lane-major C-core scan
+    (``controller._simulate_mixes_lanes``), under every scheduler, refresh
+    policy and row policy; on the Pallas backend the fused mix kernel.
     ``alone_cycles`` (flat [sum_len(mixes)] array from
     ``alone_baseline_cycles``) skips recomputing the policy-independent
     run-alone references on every policy comparison. ``use_ranking=True`` is a
@@ -109,7 +126,7 @@ def simulate_multicore_batch(mixes: list[list[Trace]], policy: Policy,
         ranks = jnp.asarray(np.stack([r for _, r in prepped]))
         controller.validate_mlp_window(stacked["mlp_window"])
 
-        if config.backend != "scan":
+        if not runs_mix_lanes(config):
             # fused Pallas mix kernel: the mix dimension is the kernel grid
             # axis, no outer vmap (docs/kernels.md). Refuses emit_commands.
             from repro.core.dram import pallas_step
@@ -122,11 +139,12 @@ def simulate_multicore_batch(mixes: list[list[Trace]], policy: Policy,
                 closed_row=config.row_policy == "closed",
                 interpret=config.backend == "pallas-interpret")
         else:
-            fn = _controller_fn(eff, sched, nb, ns, config)
-            shared, core_cycles = jax.vmap(fn)(
+            shared, core_cycles = controller._simulate_mixes_lanes(
+                eff, sched, nb, ns, config.timing, config.refresh_mode,
                 stacked["bank"], stacked["subarray"], stacked["row"],
                 stacked["is_write"], stacked["gap"], stacked["dep"],
-                stacked["mlp_window"], ranks)
+                stacked["mlp_window"], ranks,
+                closed_row=config.row_policy == "closed")
 
     alone_all = (alone_cycles if alone_cycles is not None
                  else alone_baseline_cycles(mixes, config))
@@ -148,45 +166,9 @@ def simulate_multicore_batch(mixes: list[list[Trace]], policy: Policy,
     return out
 
 
-def _controller_fn(eff: int, sched: int, nb: int, ns: int,
-                   config: SimConfig):
-    return functools.partial(
-        controller._simulate_controller, eff, sched, nb, ns,
-        config.timing, config.refresh_mode,
-        closed_row=config.row_policy == "closed")
-
-
 def simulate_multicore(traces: list[Trace], policy: Policy,
                        config: SimConfig = SimConfig(),
                        use_ranking: bool = False) -> MulticoreResult:
-    """Simulate one mix of traces sharing a channel (C-core controller)."""
-    config = _scheduler_for(config, use_ranking)
-    eff, sched, nb, ns = _controller_args(policy, config)
-    st, rank = _prep_mix(traces, policy, config)
-    controller.validate_mlp_window(st["mlp_window"])
-    if config.backend != "scan":
-        # fused Pallas mix kernel with M = 1 (docs/kernels.md)
-        from repro.core.dram import pallas_step
-        pallas_step.check_no_emit(config)
-        shared, core_cycles = pallas_step._simulate_cores_pallas(
-            eff, sched, nb, ns, config.timing, config.refresh_mode,
-            jnp.asarray(st["bank"])[None], jnp.asarray(st["subarray"])[None],
-            jnp.asarray(st["row"])[None], jnp.asarray(st["is_write"])[None],
-            jnp.asarray(st["gap"])[None], jnp.asarray(st["dep"])[None],
-            jnp.asarray(st["mlp_window"], jnp.int32)[None],
-            jnp.asarray(rank)[None],
-            closed_row=config.row_policy == "closed",
-            interpret=config.backend == "pallas-interpret")
-        shared = jax.tree_util.tree_map(lambda x: x[0], shared)
-        core_cycles = core_cycles[0]
-    else:
-        shared, core_cycles = _controller_fn(eff, sched, nb, ns, config)(
-            jnp.asarray(st["bank"]), jnp.asarray(st["subarray"]),
-            jnp.asarray(st["row"]), jnp.asarray(st["is_write"]),
-            jnp.asarray(st["gap"]), jnp.asarray(st["dep"]),
-            jnp.asarray(st["mlp_window"]), jnp.asarray(rank))
-    alone = alone_baseline_cycles([traces], config)
-    return MulticoreResult(shared=shared,
-                           core_cycles=np.asarray(core_cycles, np.float64),
-                           alone_cycles=alone,
-                           profiles=[t.profile for t in traces])
+    """Simulate one mix of traces sharing a channel: the batched entry
+    point with M = 1."""
+    return simulate_multicore_batch([traces], policy, config, use_ranking)[0]

@@ -518,11 +518,13 @@ def run_mix_sweep(grid: MixGrid, *,
                   fault_plan: FaultPlan | None = None,
                   shards: ShardPlan | int | None = None,
                   fragment_dir: str | None = None) -> MixSweepResult:
-    """Execute a :class:`MixGrid`: bucket by static shape, vmap over mixes.
+    """Execute a :class:`MixGrid`: bucket by static shape, batch the mixes.
 
     Each (policy, config) bucket becomes ONE
-    :func:`repro.core.dram.multicore.simulate_multicore_batch` call vmapped
-    over the bucket's mixes ([M, C, N] stacked traces). The policy- and
+    :func:`repro.core.dram.multicore.simulate_multicore_batch` call over the
+    bucket's mixes ([M, C, N] stacked traces): the lane-major C-core scan on
+    the scan backend, and ``stats["mix_lane_cells"]`` counts the cells whose
+    bucket runs it, before dispatch. The policy- and
     scheduler-independent run-alone baseline references are computed once per
     geometry/refresh point and shared across every policy x scheduler cell
     (mix results are not content-hash cached — the multicore scan dominates
@@ -532,6 +534,7 @@ def run_mix_sweep(grid: MixGrid, *,
     single-core runner (mix sweeps have no cache, so no prologue fragment).
     """
     from repro.core.dram.multicore import (alone_baseline_cycles,
+                                           runs_mix_lanes,
                                            simulate_multicore_batch)
     from repro.core.dram.schedulers import Scheduler
 
@@ -560,6 +563,9 @@ def run_mix_sweep(grid: MixGrid, *,
     buckets: dict[tuple, list[int]] = {}
     for i, c in enumerate(cells):
         buckets.setdefault(_bucket_key(c, grid.n_requests), []).append(i)
+    # the cells whose bucket runs the lane-major C-core scan
+    mix_lane_cells = sum(len(idxs) for idxs in buckets.values()
+                         if runs_mix_lanes(cells[idxs[0]].config))
 
     def simulate_bucket(idxs: list[int]) -> dict[int, MixCellResult]:
         bucket_cells = [cells[i] for i in idxs]
@@ -615,6 +621,7 @@ def run_mix_sweep(grid: MixGrid, *,
     stats = {
         "n_cells": len(cells),
         "n_cores": grid.n_cores,
+        "mix_lane_cells": mix_lane_cells,
         "sim_batches": report.n_batches,
         "quarantined_cells": len(cells) - len(results),
         **report.stats(),

@@ -2,14 +2,19 @@
 the completion-ring invariant, refresh in multicore, and scheduler ordering
 properties."""
 import dataclasses
+import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.dram import (ROW_SPACE_STRIDE, Policy, Scheduler, SimConfig,
-                             generate_trace, simulate, workload)
-from repro.core.dram.engine import SimResult, _RING
-from repro.core.dram.multicore import simulate_multicore, simulate_multicore_batch
+                             controller, generate_trace, simulate, workload)
+from repro.core.dram.engine import SimResult, _RING, _controller_args
+from repro.core.dram.multicore import (_prep_mix, runs_mix_lanes,
+                                       simulate_multicore,
+                                       simulate_multicore_batch)
 from repro.core.dram.trace import Trace
 
 FCFS = SimConfig(scheduler=Scheduler.FCFS)
@@ -257,3 +262,94 @@ class TestMixGridApi:
         sweep = run_sweep(grid, ResultCache())
         a, b = [c.counters for c in sweep.cells]
         assert a == b   # single-core: schedulers are inert, results identical
+
+
+#: Refresh deadlines inside the parity traces (the default tREFI of 4,160
+#: cycles is past the end of a few hundred requests).
+REF_TIMING = dataclasses.replace(
+    SimConfig().timing, t_refi=520, t_rfc=80, t_rfc_pb=32, ref_postpone_max=2)
+
+#: (policy, scheduler, SimConfig kwargs, cores) cases of the lane-major mix
+#: scan's parity test: every scheduler (PALP_RP on its PCM pack), every
+#: policy, every refresh policy, open and closed rows, C = 2 and C = 4.
+MIX_CASES = [
+    (Policy.BASELINE, Scheduler.FCFS, dict(), 2),
+    (Policy.SALP1, Scheduler.FRFCFS,
+     dict(refresh_policy="all_bank", timing=REF_TIMING), 4),
+    (Policy.SALP2, Scheduler.FRFCFS_SALP,
+     dict(refresh_policy="per_bank", timing=REF_TIMING), 2),
+    (Policy.MASA, Scheduler.TCM,
+     dict(refresh_policy="darp", timing=REF_TIMING), 4),
+    (Policy.MASA, Scheduler.PALP_RP, dict(memtech="pcm_palp"), 4),
+    (Policy.IDEAL, Scheduler.FRFCFS,
+     dict(refresh_policy="sarp", timing=REF_TIMING), 2),
+    (Policy.MASA, Scheduler.FCFS,
+     dict(refresh_policy="dsarp", timing=REF_TIMING), 2),
+    (Policy.BASELINE, Scheduler.TCM,
+     dict(refresh_policy="dsarp", timing=REF_TIMING, row_policy="closed"), 4),
+    (Policy.MASA, Scheduler.FRFCFS, dict(row_policy="closed"), 2),
+    (Policy.IDEAL, Scheduler.FRFCFS_SALP,
+     dict(refresh_policy="darp", timing=REF_TIMING, row_policy="closed"), 4),
+    (Policy.SALP2, Scheduler.PALP_RP,
+     dict(memtech="pcm_palp", row_policy="closed"), 2),
+    (Policy.SALP1, Scheduler.TCM,
+     dict(refresh_policy="sarp", timing=REF_TIMING, row_policy="closed"), 2),
+]
+
+#: Workloads of the parity mixes, and the per-core ROB windows that replace
+#: the profiles' own (they differ within every mix).
+MIX_NAMES = ("mcf", "lbm", "milc", "gups", "libquantum", "gamess")
+MIX_MLP = (3, 12, 7, 1, 9)
+
+
+def _mix_case_id(case) -> str:
+    policy, sched, kw, C = case
+    extra = [f"{k}={v}" for k, v in kw.items() if k != "timing"]
+    return "-".join([policy.name, sched.name, *extra, f"C{C}"])
+
+
+class TestLaneMajorMixes:
+    """``simulate_multicore_batch`` runs M mixes on one lane-major C-core
+    scan (``controller._simulate_mixes_lanes``): bit-identical, in every
+    SimResult field and in ``core_cycles``, to ``jax.vmap`` of the per-mix
+    controller and to ``simulate_multicore`` one mix at a time."""
+
+    @staticmethod
+    def mixes(C, n=160, M=3):
+        out = []
+        for m in range(M):
+            mix = mix_of([MIX_NAMES[(m + i) % len(MIX_NAMES)]
+                          for i in range(C)], n=n, seed=11 + m)
+            out.append([dataclasses.replace(
+                tr, mlp_window=MIX_MLP[(m + i) % len(MIX_MLP)])
+                for i, tr in enumerate(mix)])
+        return out
+
+    @pytest.mark.parametrize("case", MIX_CASES, ids=_mix_case_id)
+    def test_batch_matches_vmapped_controller(self, case):
+        policy, sched, kw, C = case
+        cfg = SimConfig(scheduler=sched, **kw)
+        assert runs_mix_lanes(cfg)
+        mixes = self.mixes(C)
+        assert all(len({t.mlp_window for t in mix}) > 1 for mix in mixes)
+        batch = simulate_multicore_batch(mixes, policy, cfg)
+
+        eff, sch, nb, ns = _controller_args(policy, cfg)
+        prepped = [_prep_mix(m, policy, cfg) for m in mixes]
+        st = {k: jnp.asarray(np.stack([s[k] for s, _ in prepped]))
+              for k in prepped[0][0]}
+        ranks = jnp.asarray(np.stack([r for _, r in prepped]))
+        shared, core_cycles = jax.vmap(functools.partial(
+            controller._simulate_controller, eff, sch, nb, ns, cfg.timing,
+            cfg.refresh_mode, closed_row=cfg.row_policy == "closed"))(
+            *(st[k] for k in ("bank", "subarray", "row", "is_write", "gap",
+                              "dep")), st["mlp_window"], ranks)
+        for i, (mix, got) in enumerate(zip(mixes, batch)):
+            vmapped = {f.name: int(np.asarray(getattr(shared, f.name))[i])
+                       for f in dataclasses.fields(SimResult)}
+            assert counters(got.shared) == vmapped, i
+            assert got.core_cycles.tolist() == np.asarray(
+                core_cycles)[i].tolist(), i
+            one = simulate_multicore(mix, policy, cfg)
+            assert counters(one.shared) == vmapped, i
+            assert one.core_cycles.tolist() == got.core_cycles.tolist(), i

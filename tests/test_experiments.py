@@ -237,3 +237,21 @@ class TestMulticoreBatch:
             assert np.array_equal(ref.core_cycles, got.core_cycles)
             assert np.array_equal(ref.alone_cycles, got.alone_cycles)
             assert ref.weighted_speedup == pytest.approx(got.weighted_speedup)
+
+    def test_mix_lane_cells_count_every_scan_cell(self):
+        """``stats["mix_lane_cells"]`` counts the cells whose bucket runs
+        the lane-major C-core scan: on the scan backend, every cell, under
+        every scheduler and refresh policy; the Pallas backends run the
+        fused mix kernel instead."""
+        from repro.core.dram import Scheduler, workload
+        from repro.core.dram.multicore import runs_mix_lanes
+        from repro.experiments import MixGrid, run_mix_sweep
+        grid = MixGrid(
+            name="t", mixes=[(workload("mcf"), workload("lbm"))],
+            policies=(Policy.BASELINE, Policy.MASA), n_requests=100,
+            config_axes={"scheduler": (Scheduler.FRFCFS, Scheduler.TCM),
+                         "refresh_policy": ("none", "darp")})
+        sweep = run_mix_sweep(grid)
+        assert sweep.stats["n_cells"] == 8
+        assert sweep.stats["mix_lane_cells"] == sweep.stats["n_cells"]
+        assert not runs_mix_lanes(SimConfig(backend="pallas-interpret"))

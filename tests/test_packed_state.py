@@ -22,7 +22,8 @@ import pytest
 from repro.core.dram import (ROW_SPACE_STRIDE, Policy, Scheduler, SimConfig,
                              generate_trace, simulate, workload)
 from repro.core.dram.engine import SimResult, simulate_stacked
-from repro.core.dram.multicore import simulate_multicore
+from repro.core.dram.multicore import (simulate_multicore,
+                                       simulate_multicore_batch)
 from repro.core.dram.trace import Trace, WorkloadProfile, stack_traces
 
 GOLDEN_PATH = os.path.join(os.path.dirname(__file__), "data",
@@ -117,7 +118,12 @@ class TestGoldenParity:
         assert not mismatches, mismatches[:3]
 
     def test_multicore_cells(self, golden, backend):
+        """Every multicore cell through ``simulate_multicore``; on the scan
+        backend also both seeds of each (config, scheduler, policy) as one
+        ``simulate_multicore_batch`` of two mixes, on the lane-major scan's
+        lanes."""
         mismatches = []
+        groups: dict[tuple, list] = {}
         for cell in golden["multicore"]:
             mix = [generate_trace(workload(m), 150, seed=cell["seed"],
                                   row_space_offset=ROW_SPACE_STRIDE * i)
@@ -130,6 +136,17 @@ class TestGoldenParity:
             if got != cell["counters"] or cc != cell["core_cycles"]:
                 mismatches.append((cell["seed"], cell["config"],
                                    cell["scheduler"], cell["policy"]))
+            groups.setdefault((cell["config"], cell["scheduler"],
+                               cell["policy"], cfg), []).append((cell, mix))
+        if backend == "scan":
+            for (*combo, cfg), members in groups.items():
+                batch = simulate_multicore_batch(
+                    [mix for _, mix in members], Policy[combo[2]], cfg)
+                for (cell, _), r in zip(members, batch):
+                    if (counters(r.shared) != cell["counters"]
+                            or [int(x) for x in r.core_cycles]
+                            != cell["core_cycles"]):
+                        mismatches.append(("batch", cell["seed"], *combo))
         assert not mismatches, mismatches
 
 

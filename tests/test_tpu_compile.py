@@ -17,7 +17,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.dram import Policy, Scheduler, SimConfig, controller
 from repro.core.dram.engine import _controller_args
-from repro.core.dram.multicore import _controller_fn
 
 B, N = 32, 8000          # the Fig. 4 grid's bucket: 32 workloads x 8,000
 M, C = 4, 4              # benchmarks.multicore_bench: four 4-core mixes
@@ -109,11 +108,29 @@ def test_vmapped_refresh_controller_compiles(one_chip):
 
 
 def test_multicore_controller_compiles(one_chip):
-    """Phase C's program: the C-core step (scheduler argmin) vmapped over
-    the mixes, as ``multicore.simulate_multicore_batch`` runs it."""
+    """The per-mix C-core controller (scheduler argmin) vmapped over the
+    mixes: the program the lane-major mix scan is checked against."""
     cfg = SimConfig(scheduler=Scheduler.FRFCFS)
     eff, sched, nb, ns = _controller_args(Policy.MASA, cfg)
     fields, mlp = _requests(one_chip, M, C)
     rank = jax.ShapeDtypeStruct((M, C), jnp.int32, sharding=one_chip)
-    fn = jax.jit(jax.vmap(_controller_fn(eff, sched, nb, ns, cfg)))
+    fn = jax.jit(jax.vmap(functools.partial(
+        controller._simulate_controller, eff, sched, nb, ns, cfg.timing,
+        cfg.refresh_mode)))
     _fits(fn.lower(*fields, mlp, rank).compile())
+
+
+@pytest.mark.parametrize("scheduler", [Scheduler.FRFCFS, Scheduler.TCM],
+                         ids=lambda s: s.name)
+def test_mixes_lanes_compiles(one_chip, scheduler):
+    """Phase C's program: the lane-major C-core scan over four 4-core mixes
+    of 8,000 requests a core, as ``multicore.simulate_multicore_batch``
+    runs the mixes cell's buckets."""
+    cfg = SimConfig(scheduler=scheduler)
+    eff, sched, nb, ns = _controller_args(Policy.MASA, cfg)
+    fields, mlp = _requests(one_chip, M, C)
+    rank = jax.ShapeDtypeStruct((M, C), jnp.int32, sharding=one_chip)
+    compiled = controller._simulate_mixes_lanes.lower(
+        eff, sched, nb, ns, cfg.timing, cfg.refresh_mode, *fields, mlp,
+        rank).compile()
+    _fits(compiled)

@@ -117,18 +117,6 @@ def suite_traces(n: int = N_REQUESTS, seed: int = SEED):
     return [trace_for(p, n, cfg, seed) for p in PAPER_WORKLOADS]
 
 
-def suite_ipc(traces, policy):
-    """Per-workload IPC under one policy (vectorized across the suite)."""
-    from repro.core.dram import PAPER_WORKLOADS, simulate_batch
-    from repro.core.dram.timing import DEFAULT_CORE
-    res = simulate_batch(traces, policy)
-    total = np.asarray(res.total_cycles, np.float64)
-    nreq = np.asarray(res.n_requests, np.float64)
-    mpki = np.array([p.mpki for p in PAPER_WORKLOADS])
-    instr = nreq * 1000.0 / mpki
-    return instr / (total * DEFAULT_CORE.cpu_per_dram), res
-
-
 def command_slice(trace, policy, config, out_path: str) -> dict:
     """One command-level fidelity cell: export, check, cross-validate, dump.
 

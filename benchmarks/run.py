@@ -79,11 +79,9 @@ REGISTRY: tuple[Bench, ...] = (
           "Sec. 4/9.3: policy x scheduler x mix grid, refresh on"),
     Bench("mapping", "benchmarks.mapping_bench", ("mapping",),
           "Frontend: address-mapping x policy sensitivity (dense footprint)"),
-    Bench("perf", "benchmarks.perf_bench", ("perf",),
-          "Simulator throughput trajectory (writes BENCH_perf.json)"),
     Bench("kernels", "benchmarks.kernel_bench", ("accel", "kernel"),
           "Layer B: revived Pallas kernel residency + oracle agreement "
-          "(validated artifact, like smoke/mapping/perf/refresh)"),
+          "(validated artifact, like smoke/mapping/refresh)"),
     Bench("serving", "benchmarks.serving_bench", ("accel",),
           "Layer C: SALP-aware scheduler"),
     Bench("smoke", "benchmarks.smoke", ("smoke",),

@@ -9,10 +9,8 @@ Usage::
 
     python -m benchmarks.validate artifacts/smoke.json --suite smoke \
         --check-commands artifacts/commands_smoke.trace
-    python -m benchmarks.validate artifacts/BENCH_perf.json --suite perf \
-        --perf-guard
 
-Suites: ``smoke`` / ``mapping`` / ``perf`` / ``refresh`` / ``kernels``
+Suites: ``smoke`` / ``mapping`` / ``refresh`` / ``kernels`` / ``memtech``
 (auto-detected from the artifact's ``results`` keys when ``--suite`` is
 omitted). Exit code 0 =
 valid, 1 = validation failed, 2 = bad invocation.
@@ -27,12 +25,6 @@ checked trace, and the summarized trace are provably the same bytes.
 (``benchmarks.run --shards/--fragments``) and pins the merged cells and
 quarantine records against the artifact's sweeps — proving the streamed
 fragments reassemble bit-identically to the artifact that shipped.
-
-``--perf-guard`` (perf suite only) additionally compares the artifact's
-``default_req_per_s`` against the committed seeded reference
-(``benchmarks.perf_bench.REF_REQ_PER_S``) and emits a GitHub ``::warning``
-annotation — never a failure; CI hosts are too noisy to gate on speed — when
-throughput drops below ``PERF_GUARD_RATIO`` of the reference.
 """
 from __future__ import annotations
 
@@ -40,9 +32,6 @@ import argparse
 import json
 import sys
 from typing import Callable
-
-#: Warn (never fail) when default_req_per_s < ratio * committed reference.
-PERF_GUARD_RATIO = 0.5
 
 
 class ValidationError(AssertionError):
@@ -173,79 +162,6 @@ def validate_mapping(doc: dict) -> str:
     _check(specs == {"contiguous", "golden", "xor"}, f"mapping specs: {specs}")
     return (f"mapping ok: contiguous=+{m['gain_contiguous_MASA']:.1f}% "
             f"xor=+{m['gain_xor_MASA']:.1f}%")
-
-
-def validate_perf(doc: dict, guard: bool = False) -> str:
-    validate_common(doc)
-    perf = doc["results"].get("perf") or {}
-    _check(perf.get("default_req_per_s", 0) > 0, f"default_req_per_s: {perf}")
-    _check(perf.get("n_cells") == len(perf.get("cells", [])) != 0,
-           "n_cells != len(cells)")
-    for cell in perf["cells"]:
-        _check(set(cell) >= {"name", "n_requests", "cold_s", "warm_s",
-                             "compile_s", "req_per_s"},
-               f"cell fields: {sorted(cell)}")
-    backends = perf.get("backends")
-    if backends is not None:  # older artifacts predate the backend axis
-        _check("scan" in backends, f"backends missing scan: {backends}")
-        for b, row in backends.items():
-            _check(row.get("single_req_per_s", 0) > 0
-                   and row.get("batch32_req_per_s", 0) > 0,
-                   f"backend {b}: {row}")
-        kvs = perf.get("kernel_vs_scan") or {}
-        _check(kvs.get("kernel_backend") in backends,
-               f"kernel_vs_scan backend: {kvs}")
-    msg = (f"perf ok: {doc['git_sha']} "
-           f"{perf['default_req_per_s'] / 1e3:.1f}k req/s")
-    if guard:
-        msg += "; " + perf_guard(perf, doc.get("trajectory"))
-    return msg
-
-
-def perf_guard(perf: dict, trajectory: list | None = None) -> str:
-    """Warn-only trajectory guard: committed reference, kernel-vs-scan,
-    and previous-artifact comparison.
-
-    Reads the pinned ``REF_REQ_PER_S`` origin point plus (when the
-    artifact carries them) the same-process ``kernel_vs_scan`` ratios and
-    the last committed ``trajectory`` point; a throughput drop below
-    ``PERF_GUARD_RATIO`` of either reference emits a GitHub ``::warning``
-    annotation on stdout (picked up by the Actions runner) but never fails
-    validation — CI hosts are too noisy to gate on speed.
-    """
-    from benchmarks.perf_bench import REF_REQ_PER_S
-    ref = REF_REQ_PER_S["single/MASA/8x8"]
-    got = perf["default_req_per_s"]
-    parts = []
-    if got < PERF_GUARD_RATIO * ref:
-        print(f"::warning title=Perf trajectory::default_req_per_s "
-              f"{got:.0f} fell below {PERF_GUARD_RATIO:.0%} of the committed "
-              f"reference {ref:.0f} (ratio {got / ref:.2f}). CI hosts are "
-              f"noisy — investigate only if this persists across runs.")
-        parts.append(f"guard: BELOW reference ({got / ref:.2f}x, warned)")
-    else:
-        parts.append(f"guard: {got / ref:.2f}x of committed reference")
-
-    kvs = perf.get("kernel_vs_scan")
-    if kvs:
-        kb = kvs.get("kernel_backend")
-        # the interpret leg is an emulation (parity path, expected < 1),
-        # so the ratio is recorded, not guarded
-        parts.append(f"{kb} vs scan: single {kvs.get('single')}x, "
-                     f"batch32 {kvs.get('batch32')}x")
-
-    last = (trajectory or [{}])[-1]
-    prev = last.get("batch32_req_per_s")
-    now = next((c["req_per_s"] for c in perf.get("cells", ())
-                if c["name"] == "batch32/MASA/8x8"), None)
-    if prev and now:
-        parts.append(f"batch32 {now / prev:.2f}x vs previous artifact "
-                     f"({str(last.get('git_sha'))[:8]})")
-        if now < PERF_GUARD_RATIO * prev:
-            print(f"::warning title=Perf trajectory::batch32 req/s "
-                  f"{now:.0f} fell below {PERF_GUARD_RATIO:.0%} of the "
-                  f"previous committed artifact's {prev:.0f}.")
-    return "; ".join(parts)
 
 
 def validate_kernels(doc: dict) -> str:
@@ -416,7 +332,6 @@ def validate_memtech(doc: dict) -> str:
 SUITES: dict[str, Callable[[dict], str]] = {
     "smoke": validate_smoke,
     "mapping": validate_mapping,
-    "perf": validate_perf,
     "refresh": validate_refresh,
     "kernels": validate_kernels,
     "memtech": validate_memtech,
@@ -433,9 +348,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("artifact", help="path to a repro.bench/v1 JSON artifact")
     ap.add_argument("--suite", choices=sorted(SUITES), default=None,
                     help="suite checks to apply (default: auto-detect)")
-    ap.add_argument("--perf-guard", action="store_true",
-                    help="perf only: warn-only trajectory comparison against "
-                         "the committed seeded reference")
     ap.add_argument("--check-commands", metavar="PATH", default=None,
                     help="re-parse a command-trace dump, re-run the JEDEC "
                          "checker, and pin its sha against the artifact's "
@@ -465,13 +377,9 @@ def main(argv: list[str] | None = None) -> int:
               f"{sorted(doc.get('results') or {})}; pass --suite",
               file=sys.stderr)
         return 2
-    if args.perf_guard and suite != "perf":
-        print("--perf-guard only applies to --suite perf", file=sys.stderr)
-        return 2
 
     try:
-        msg = (validate_perf(doc, guard=True) if suite == "perf"
-               and args.perf_guard else SUITES[suite](doc))
+        msg = SUITES[suite](doc)
         if args.check_commands:
             msg += "; commands: " + check_commands_file(
                 args.check_commands, doc, suite)

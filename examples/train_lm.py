@@ -4,8 +4,7 @@ the loss actually dropping.
 
   PYTHONPATH=src python examples/train_lm.py --arch smollm-135m --steps 200
 
-The same loop drives the full configs on a real cluster (launch/train.py);
-here the reduced config keeps it CPU-sized. The injected failure at step 120
+The reduced config keeps it CPU-sized. The injected failure at step 120
 exercises SupervisedRun: the loop restarts from the step-100 checkpoint and
 replays the exact same data (step-keyed pipeline), finishing all steps.
 """

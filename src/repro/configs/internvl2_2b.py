@@ -1,7 +1,7 @@
 """internvl2-2b [vlm]: 24L d_model=2048 16H (GQA kv=8) d_ff=8192 vocab=92553 —
 InternViT + InternLM2 [arXiv:2404.16821; hf].
 
-The InternViT frontend is a STUB (input_specs provides precomputed patch
+The InternViT frontend is a STUB (data/synth.batch_shapes gives precomputed patch
 embeddings); the LM backbone consumes [patch_embeds ++ embedded text tokens].
 modality_tokens = 1024 patch positions in the canonical shapes."""
 from repro.configs.base import AttnConfig, LayerSpec, ModelConfig

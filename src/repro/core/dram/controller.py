@@ -35,7 +35,7 @@ Scan carry (see :mod:`repro.core.dram.state_layout`): the engine's four
 packed buffers plus a ``[C, CORE_F]`` per-core vector, the ``[C, _RING]``
 completion rings, and (when refreshing) a ``[nb, REF_F]`` refresh table —
 six int32 buffers total, updated with single-row dynamic scatters. The
-scan's ``unroll`` factor is tunable (``_SCAN_UNROLL``, swept to 1 on CPU);
+scan's ``unroll`` factor is tunable (``_SCAN_UNROLL``, 1: a CPU choice);
 input-buffer donation was evaluated and removed — the scan already updates
 its carry in place and the only outputs are a handful of scalars, so XLA
 finds no donated buffer to reuse (it warns instead). docs/performance.md
@@ -58,24 +58,21 @@ from repro.core.dram.timing import DramTiming
 _RING = _engine._RING
 _NEG = _engine._NEG
 
-#: Partial-unroll factor for the controller scan, chosen by the ``unroll``
-#: sweep in ``benchmarks/perf_bench.py`` (results are bit-identical for any
-#: value). The swept answer on CPU is **no unroll**: the step is almost
-#: entirely sequential gather/scatter, so unrolling multiplies code size
-#: without exposing parallelism — unroll=8 halved throughput and unroll=64
-#: took minutes to compile (see docs/performance.md for the numbers).
+#: Partial-unroll factor for the per-trace controller scan
+#: (:func:`_simulate_controller`; results are bit-identical for any value).
+#: It serves only ``simulate()``, command export and closed-row batches, none
+#: of which a benchmark cell runs, so it is a CPU choice: **no unroll**. The
+#: step is almost entirely sequential gather/scatter, so unrolling multiplies
+#: code size without exposing parallelism.
 _SCAN_UNROLL = 1
 
-#: Partial-unroll factor for the LANE-BATCHED scan
-#: (:func:`_simulate_stacked_lanes`), swept separately in
-#: ``benchmarks/perf_bench.py`` (``lanes_unroll*`` cells; bit-identical for
-#: any value). Unlike the 1-lane step, the lane step carries O(B) vector
-#: work per sequential dependency, so a 2-way unroll overlaps one step's
-#: scatter with the next step's gather math without blowing up code size —
-#: ~1.1-1.2x on batch32; unroll=4 regresses (see docs/performance.md).
-#: The lane-major mix scan (:func:`_simulate_mixes_lanes`) takes it too: on
-#: a TPU v5e a step of four 4-core mixes costs 11.76 us at 2 against 12.19
-#: at 1 (PERF.md).
+#: Partial-unroll factor for the lane-batched scans
+#: (:func:`_simulate_stacked_lanes` and the lane-major mix scan
+#: :func:`_simulate_mixes_lanes`; bit-identical for any value). The lane
+#: step carries O(B) vector work per sequential dependency, so a 2-way
+#: unroll overlaps one step's scatter with the next step's gather math.
+#: Measured on a TPU v5e: a step of four 4-core mixes costs 11.76 us at 2
+#: against 12.19 us at 1 (PERF.md, the lane-major mix scan entry).
 _LANES_UNROLL = 2
 
 

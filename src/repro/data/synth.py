@@ -1,8 +1,7 @@
 """Synthetic batches: the single source of truth for per-family input shapes.
 
-``batch_shapes(cfg, B, S)`` returns {name: (shape, dtype)} — used both by the
-data pipeline (real arrays) and by launch/dryrun.input_specs
-(ShapeDtypeStructs). Conventions (DESIGN.md Sec. 6):
+``batch_shapes(cfg, B, S)`` returns {name: (shape, dtype)} — used by the
+data pipeline (real arrays). Conventions (DESIGN.md Sec. 6):
 
   text LM        tokens/labels [B, S]
   vlm            patch_embeds [B, P, D] + tokens [B, S-P] + labels [B, S]

@@ -1,6 +1,6 @@
 """Sharding rules: parameter/batch/cache PartitionSpecs for the production mesh.
 
-Mesh axes (launch/mesh.py): ("data", "model") single-pod, ("pod", "data",
+Mesh axes: ("data", "model") single-pod, ("pod", "data",
 "model") multi-pod. "data" carries DP+FSDP (ZeRO-3-style parameter sharding),
 "model" carries TP/EP; "pod" joins "data" for the gradient reduction (pure DP
 across pods — FSDP stays intra-pod so cross-pod links only carry gradients).

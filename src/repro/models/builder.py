@@ -5,7 +5,7 @@ parameters carry a leading ``[n_repeats, ...]`` axis and the forward pass scans
 over it, so compiled HLO is O(pattern size), not O(depth) — required to keep
 the 88-layer / 779 B-parameter dry-runs compilable.
 
-Batch conventions (see launch/dryrun.py input_specs):
+Batch conventions (see data/synth.py batch_shapes):
   text LM:  {"tokens": [B,S] int32, "labels": [B,S] int32}
   vlm:      {"patch_embeds": [B,P,D] bf16, "tokens": [B,S-P], "labels": [B,S]}
   audio enc-dec: {"enc_embeds": [B,Se,D] bf16, "tokens": [B,Sd], "labels": [B,Sd]}

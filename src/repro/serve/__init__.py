@@ -1,7 +1,6 @@
 from repro.serve.kvcache import PagedKVCache, PageAllocator
 from repro.serve.scheduler import SalpScheduler, Request
 from repro.serve.engine import ServingEngine
-from repro.serve.what_if import SweepIndex, what_if
 
 __all__ = ["PagedKVCache", "PageAllocator", "SalpScheduler", "Request",
-           "ServingEngine", "SweepIndex", "what_if"]
+           "ServingEngine"]

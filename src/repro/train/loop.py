@@ -1,7 +1,6 @@
 """The training loop: data pipeline + train step + checkpointing + watchdog.
 
-Runs at any scale: reduced configs on CPU (tests/examples) or the production
-mesh on a real cluster (launch/train.py). Fault-tolerance contract
+Runs reduced configs on CPU (tests/examples). Fault-tolerance contract
 (DESIGN.md Sec. 7): step-keyed deterministic data, async checkpoints every
 ``ckpt_every`` steps, supervised restarts resuming from the latest checkpoint.
 """

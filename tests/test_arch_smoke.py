@@ -1,6 +1,5 @@
 """Per-architecture smoke tests: reduced config, one forward + one train step
-on CPU, asserting output shapes and finiteness (no NaNs). The FULL configs are
-exercised only via the dry-run (launch/dryrun.py)."""
+on CPU, asserting output shapes and finiteness (no NaNs)."""
 import functools
 
 import jax

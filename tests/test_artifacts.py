@@ -5,7 +5,7 @@ caught here, locally, not in a workflow run.
 Two layers:
 
 * **synthetic fixtures** — minimal valid documents per suite, built in
-  memory, so every corruption/CLI/guard test runs in ANY checkout
+  memory, so every corruption/CLI test runs in ANY checkout
   (``artifacts/`` is gitignored; real artifacts may be absent);
 * **local artifacts** — when a previous bench run left real artifacts on
   disk they must validate too (skipped per-file when absent).
@@ -22,18 +22,12 @@ REPO = Path(__file__).resolve().parent.parent
 LOCAL_ARTIFACTS = {
     "smoke": REPO / "artifacts" / "smoke.json",
     "mapping": REPO / "artifacts" / "mapping_smoke.json",
-    "perf": REPO / "artifacts" / "BENCH_perf.json",
     "refresh": REPO / "artifacts" / "refresh.json",
     "kernels": REPO / "artifacts" / "kernels.json",
     "memtech": REPO / "artifacts" / "memtech.json",
 }
 
 _COMMON = {"schema_version": "repro.bench/v1", "git_sha": "f" * 40, "seed": 7}
-
-
-def _perf_cell(name: str) -> dict:
-    return {"name": name, "n_requests": 2000, "cold_s": 1.0, "warm_s": 0.01,
-            "compile_s": 0.99, "req_per_s": 200000.0}
 
 
 def _refresh_pens(pol: str) -> dict:
@@ -62,13 +56,6 @@ def make_doc(suite: str) -> dict:
                             "cells": [{"overrides": {"mapping": m}}
                                       for m in ("contiguous", "golden",
                                                 "xor")]}]}
-    if suite == "perf":
-        return {**_COMMON,
-                "results": {"perf": {
-                    "default_req_per_s": 200000.0, "n_cells": 2,
-                    "cells": [_perf_cell("single/MASA/8x8"),
-                              _perf_cell("batch32/MASA/8x8")]}},
-                "sweeps": []}
     if suite == "refresh":
         return {**_COMMON,
                 "results": {"refresh": {
@@ -149,13 +136,6 @@ def test_mapping_rejects_collapse_regression():
         doc["results"]["mapping"]["gain_xor_MASA"]
     with pytest.raises(V.ValidationError, match="contiguous"):
         V.validate_mapping(doc)
-
-
-def test_perf_rejects_cell_count_mismatch():
-    doc = make_doc("perf")
-    doc["results"]["perf"]["cells"] = doc["results"]["perf"]["cells"][:-1]
-    with pytest.raises(V.ValidationError, match="n_cells"):
-        V.validate_perf(doc)
 
 
 def test_refresh_rejects_inverted_ladder():
@@ -245,26 +225,6 @@ def test_kernels_rejects_broken_ladder():
         V.validate_kernels(doc)
 
 
-def test_perf_guard_warns_but_does_not_fail(capsys, tmp_path):
-    doc = make_doc("perf")
-    doc["results"]["perf"]["default_req_per_s"] = 1.0   # absurdly slow
-    doc["results"]["perf"]["cells"][0]["req_per_s"] = 1.0
-    p = tmp_path / "slow_perf.json"
-    p.write_text(json.dumps(doc))
-    rc = V.main([str(p), "--suite", "perf", "--perf-guard"])
-    out = capsys.readouterr().out
-    assert rc == 0, "the guard is warn-only, never a failure"
-    assert "::warning" in out and "Perf trajectory" in out
-
-
-def test_perf_guard_quiet_when_healthy(capsys, tmp_path):
-    p = tmp_path / "perf.json"
-    p.write_text(json.dumps(make_doc("perf")))
-    rc = V.main([str(p), "--suite", "perf", "--perf-guard"])
-    out = capsys.readouterr().out
-    assert rc == 0 and "::warning" not in out
-
-
 def test_cli_exit_codes(tmp_path, capsys):
     ok = tmp_path / "refresh.json"
     ok.write_text(json.dumps(make_doc("refresh")))
@@ -279,7 +239,6 @@ def test_cli_exit_codes(tmp_path, capsys):
     nosuite = tmp_path / "nosuite.json"
     nosuite.write_text(json.dumps({"results": {}}))
     assert V.main([str(nosuite)]) == 2                 # cannot detect suite
-    assert V.main([str(ok), "--perf-guard"]) == 2      # guard needs perf
     capsys.readouterr()
 
 

@@ -16,6 +16,7 @@ import dataclasses
 import json
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -148,6 +149,46 @@ class TestGoldenParity:
                             != cell["core_cycles"]):
                         mismatches.append(("batch", cell["seed"], *combo))
         assert not mismatches, mismatches
+
+
+@pytest.fixture
+def release_programs():
+    """Drop every compiled program once the test ends. Each one holds
+    hundreds of memory mappings, and a process that runs this whole module
+    otherwise comes near the kernel's per-process limit on them."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("config_name", sorted(CONFIGS))
+def test_golden_single_core_cells_stacked(golden, config_name,
+                                          release_programs):
+    """Every golden single-core cell of one configuration through
+    ``simulate_stacked``, one batch per policy, against the pinned counters.
+
+    ``test_single_core_cells`` runs them through ``simulate``, the per-trace
+    controller; this pins the batched path the sweeps run: the lanes scan
+    for open rows (refresh ladder, IDEAL remap, per-lane ``mlp_window`` on
+    the ring's dynamic path) and ``vmap`` of the controller for closed rows.
+    """
+    by_policy: dict[str, list] = {}
+    for cell in golden["single"]:
+        if cell["config"] == config_name:
+            by_policy.setdefault(cell["policy"], []).append(cell)
+    assert set(by_policy) == {p.name for p in Policy}
+    cfg = SimConfig(**CONFIGS[config_name])
+    mismatches = []
+    for policy, cells in by_policy.items():
+        res = simulate_stacked(
+            stack_traces([random_trace(c["seed"]) for c in cells]),
+            Policy[policy], cfg)
+        for i, cell in enumerate(cells):
+            got = {f.name: int(np.asarray(getattr(res, f.name))[i])
+                   for f in dataclasses.fields(SimResult)}
+            if got != cell["counters"]:
+                mismatches.append((cell["seed"], policy, got,
+                                   cell["counters"]))
+    assert not mismatches, mismatches[:3]
 
 
 class TestFixtureShape:

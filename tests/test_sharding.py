@@ -40,7 +40,6 @@ from repro.experiments import (FRAGMENT_SCHEMA, FaultPlan, MixGrid,
                                write_artifact)
 from repro.experiments import runner as runner_mod
 from repro.experiments.sharding import fragment_fingerprint
-from repro.serve import SweepIndex, what_if
 
 WLS = tuple(p for p in PAPER_WORKLOADS if p.name in ("mcf", "lbm"))
 N = 96
@@ -475,45 +474,6 @@ class TestSmokeQuarantineAware:
         assert out["fault_injection"] is True
         # b0 strands 3 sweep cells and 2 mix cells — accounted, not fatal
         assert out["quarantined"] == 5
-
-
-# ---------------------------------------------------------------------------
-# what-if queries over fragments (serve layer)
-# ---------------------------------------------------------------------------
-
-class TestWhatIf:
-    def test_ranks_candidates_from_fragment_directory(self, tmp_path):
-        d = tmp_path / "frags"
-        run_sweep(tiny_grid(n_geoms=2), ResultCache(), shards=2,
-                  fragment_dir=str(d))
-        ans = what_if("mcf", fragments=d)
-        assert ans["n_candidates"] == 4            # 2 policies x 2 geometries
-        assert ans["minimize"] is True
-        vals = [c["total_cycles"] for c in ans["ranking"]]
-        assert vals == sorted(vals)
-        assert ans["best"]["total_cycles"] == min(vals)
-        narrowed = what_if("mcf", {"n_subarrays": 8}, fragments=d)
-        assert narrowed["n_candidates"] == 2
-        assert all(c["overrides"]["n_subarrays"] == 8
-                   for c in narrowed["ranking"])
-
-    def test_artifact_source_and_errors(self):
-        sweep = run_sweep(tiny_grid(), ResultCache())
-        idx = SweepIndex.from_artifact(sweep.to_json())
-        best = idx.what_if("lbm", metric="ipc")
-        assert best["minimize"] is False
-        with pytest.raises(LookupError, match="no cells"):
-            idx.what_if("nonexistent_workload")
-        with pytest.raises(ValueError, match="exactly one"):
-            what_if("mcf")
-
-    def test_counts_quarantined_matches(self):
-        sweep = run_sweep(tiny_grid(), ResultCache(), resilience=FAST,
-                          fault_plan=FaultPlan.parse("raise@c2:p"), shards=2)
-        idx = SweepIndex([sweep.to_json()])
-        ans = idx.what_if("mcf")
-        assert ans["n_candidates"] == 1            # SALP1 survived
-        assert ans["n_quarantined_matches"] == 1   # BASELINE was stranded
 
 
 # ---------------------------------------------------------------------------
